@@ -28,7 +28,6 @@ from jax.sharding import PartitionSpec as P             # noqa: E402
 from benchmarks.common import emit, time_fn             # noqa: E402
 from repro.core import collectives as coll              # noqa: E402
 from repro.core.perfmodel import fit_alpha_beta         # noqa: E402
-from repro import compat                                # noqa: E402
 from repro.parallel.mesh import make_mesh               # noqa: E402
 
 SIZES = [2 ** i for i in range(12, 21)]   # elements
@@ -55,19 +54,19 @@ def main():
     mesh = make_mesh((4, 2), ("data", "model"))
 
     def ag_mp(x):
-        return compat.shard_map(
+        return jax.shard_map(
             lambda v: coll.mp_all_gather(v, ("model",), 2, axis=0),
             mesh=mesh, in_specs=P(("data", "model"), None),
             out_specs=P(("data",), None), check_vma=False)(x)
 
     def a2a_ep_esp(x):
-        return compat.shard_map(
+        return jax.shard_map(
             lambda v: coll.ep_esp_all_to_all(v, ("data",), ("model",)),
             mesh=mesh, in_specs=P(("data", "model"), None),
             out_specs=P(("data", "model"), None), check_vma=False)(x)
 
     def a2a_ep(x):
-        return compat.shard_map(
+        return jax.shard_map(
             lambda v: coll.ep_all_to_all(v, ("data",)),
             mesh=mesh, in_specs=P(("data",), None),
             out_specs=P(("data",), None), check_vma=False)(x)

@@ -4,6 +4,11 @@ Prints ``name,us_per_call,derived`` CSV rows.  Analytic benches run
 in-process; measured multi-device benches run in subprocesses with 8 fake
 CPU devices (the main process must keep seeing 1 device).
 
+A CPU tool by design: it pins ``JAX_PLATFORMS=cpu`` for itself and every
+child it starts, so on a TPU host it neither takes the chip nor contends
+with the process that holds it.  Its timings are CPU timings, never
+device numbers; ``chip_smoke.py`` is the run on the chip.
+
 Every row is also collected into the canonical ``BENCH_pr10.json`` at the
 repo root — the machine-readable perf trajectory successive PRs diff
 against (schema: ``{"rows": [{"name", "us_per_call", "derived"}, ...]}``).
@@ -17,6 +22,8 @@ import json
 import os
 import subprocess
 import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"      # before any bench imports jax
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 for _p in (_ROOT, os.path.join(_ROOT, "src")):   # python benchmarks/run.py

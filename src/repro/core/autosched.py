@@ -453,9 +453,10 @@ def measure_candidates(mesh, dims, cfg, *, tokens: int, d_model: int,
     data and records median wall time.  ``tokens`` is the *global* pool
     (B*L of the real layer): the nested ``apply_moe`` re-shards it over
     the same batch axes, so each candidate runs at the true per-device
-    token count.  Raises if every candidate fails; individual failures
-    score ``inf``.  The imports are lazy to keep ``moe -> autosched``
-    one-directional at module load.
+    token count.  Raises if every candidate fails; off the TPU,
+    individual failures score ``inf`` (on the TPU any failure raises).
+    The imports are lazy to keep ``moe -> autosched`` one-directional at
+    module load.
     """
 
     def _measure(candidates):
@@ -492,6 +493,8 @@ def measure_candidates(mesh, dims, cfg, *, tokens: int, d_model: int,
                 ts.sort()
                 out[cand] = ts[len(ts) // 2]
             except Exception as e:  # noqa: BLE001 — unlowerable candidate
+                if jax.default_backend() == "tpu":
+                    raise   # on the chip a failed candidate fails the run
                 out[cand] = float("inf")
                 errors[cand] = repr(e)
         if errors and all(t == float("inf") for t in out.values()):

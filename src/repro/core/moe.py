@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 import itertools
 
-from repro import compat, obs
+from repro import obs
 from repro.core import autosched, executor
 from repro.core import plan as planlib
 from repro.core.collectives import CommConfig
@@ -371,7 +371,7 @@ def apply_moe(x, params: dict, *, mesh, dims: ParallelDims, cfg: MoEConfig,
     # which apply_moe call / schedule / wire they belong to.
     with obs.trace_tag(moe_call=next(_TRACE_ORDINAL), schedule=sched,
                        wire=wire):
-        y, aux = compat.shard_map(
+        y, aux = jax.shard_map(
             shard_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False)(xt, params["wg"], params["w1"], w3,
                              params["w2"])

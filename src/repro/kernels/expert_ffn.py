@@ -86,6 +86,7 @@ def expert_ffn(x, w1, w3, w2, *, act="silu", block_t=128, block_f=256,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_t, M), lambda e, it, jf: (e, it, 0)),
         out_shape=jax.ShapeDtypeStruct((E, t_pad, M), x.dtype),
+        name="expert_ffn",
         interpret=interpret,
     )(*operands)
     return out[:, :T] if t_pad != T else out

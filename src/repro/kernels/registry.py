@@ -40,6 +40,7 @@ in ``ref.py``, and register both:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from dataclasses import dataclass
@@ -83,6 +84,8 @@ DEFAULT = KernelConfig()
 
 # (op name, backend) -> builder(cfg: KernelConfig, static: dict) -> callable
 _REGISTRY: dict = {}
+# open ``record_resolved`` blocks, each an {op: backend} dict
+_RECORDERS: list = []
 
 
 def register(name: str, backend: str):
@@ -99,6 +102,19 @@ def register(name: str, backend: str):
 
 def list_ops() -> tuple:
     return tuple(sorted({n for n, _ in _REGISTRY}))
+
+
+@contextlib.contextmanager
+def record_resolved():
+    """Yield a dict that fills with ``{op: backend}`` for every
+    ``get_op`` inside the block — traced inside it, a step records the
+    backend each of its ops resolved to."""
+    rec: dict = {}
+    _RECORDERS.append(rec)
+    try:
+        yield rec
+    finally:
+        _RECORDERS.remove(rec)
 
 
 def available_backends(name: str) -> tuple:
@@ -135,6 +151,8 @@ def get_op(name: str, *, backend: Optional[str] = None,
     """
     cfg = cfg or DEFAULT
     b = resolve_backend(backend, cfg)
+    for rec in _RECORDERS:
+        rec[name] = b
     if (name, b) not in _REGISTRY:
         known = ", ".join(f"{n}:{bk}" for n, bk in sorted(_REGISTRY))
         raise KeyError(f"no kernel op {name!r} for backend {b!r} ({known})")

@@ -31,5 +31,6 @@ def rmsnorm(x, scale, *, eps=1e-5, block_r=256, interpret=None):
                   pl.BlockSpec((D,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_r, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(x, scale)

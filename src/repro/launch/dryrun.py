@@ -1,7 +1,10 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
-# count at first init.  REPRO_DRYRUN_DEVICES overrides for scaled-down CI.
+# The lines above MUST run before any jax import: jax locks the platform
+# and the device count at first init.  The dry-run is a CPU fake-device
+# tool by design: pinning the CPU keeps it (and any child) off a TPU
+# host's chip.  REPRO_DRYRUN_DEVICES overrides the count for scaled-down CI.
 if os.environ.get("REPRO_DRYRUN_DEVICES"):
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                + os.environ["REPRO_DRYRUN_DEVICES"])
@@ -13,6 +16,9 @@ if not os.environ.get("REPRO_DRYRUN_FULL_OPT"):
     os.environ["XLA_FLAGS"] += " --xla_backend_optimization_level=0"
 
 """Multi-pod dry-run: prove the distribution config is coherent.
+
+Runs on CPU fake devices only (``JAX_PLATFORMS=cpu`` is pinned above,
+for this process and its children), so it never takes a TPU host's chip.
 
 For every (architecture x input shape x mesh) combination, lower + compile
 the appropriate step function (train_step / prefill / serve_step) against

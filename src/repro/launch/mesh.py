@@ -26,6 +26,21 @@ def make_test_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
+def local_mesh(cfg, devices=None):
+    """(mesh, dims) over the local devices (default: all of them),
+    folded into (data, model): one device is (1, 1), n > 1 is
+    (n // 2, 2).  MoE archs run EP over ``data`` and ESP == MP over
+    ``model``; dense archs run DP over ``data`` and MP over ``model``."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
+    d = n // 2 if n > 1 else 1
+    mesh = make_mesh((d, n // d), ("data", "model"), devices=devices)
+    dims = (ParallelDims(ep=("data",), esp=("model",), mp=("model",))
+            if cfg.moe is not None
+            else ParallelDims(dp=("data",), mp=("model",)))
+    return mesh, dims
+
+
 def dims_for(cfg, multi_pod: bool = False) -> ParallelDims:
     """Logical parallel dims for an architecture on the production mesh."""
     return production_dims(multi_pod=multi_pod, moe=cfg.moe is not None)

@@ -21,18 +21,14 @@ import numpy as np
 
 from repro import obs
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import local_mesh
 from repro.models import build_model
-from repro.parallel.mesh import ParallelDims, make_mesh
 from repro.serve import Engine, SamplerConfig, latency_stats
 
 
 def build_engine(args, cfg, model):
-    n_dev = jax.device_count()
-    d = max(1, n_dev // 2) if n_dev > 1 else 1
-    mesh = make_mesh((d, max(n_dev // d, 1)), ("data", "model"))
-    dims = (ParallelDims(ep=("data",), esp=("model",), mp=("model",))
-            if cfg.moe is not None
-            else ParallelDims(dp=("data",), mp=("model",)))
+    mesh, dims = local_mesh(cfg)
     schedule = None if args.schedule in (None, "auto") else args.schedule
     max_batch = args.max_batch
     if max_batch <= 0:               # perf-model bucket sizing (t_decode)
@@ -140,6 +136,7 @@ def main():
         ap.error("--requests must be >= 1")
     if args.trace and not args.metrics_dir:
         ap.error("--trace requires --metrics-dir")
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 8)
         args.gen = min(args.gen, 8)
@@ -216,7 +213,11 @@ def main():
             try:
                 st = trace_schedule(mesh, dims, cfg.moe,
                                     engine.max_batch, sched, infer=True)
-            except Exception as e:   # tiny decode pools can be untraceable
+            except Exception as e:
+                # tiny CPU decode pools can be untraceable; on the chip a
+                # failed trace is a failed run
+                if jax.default_backend() == "tpu":
+                    raise
                 print(f"--trace: {type(e).__name__}: {e}; skipping",
                       flush=True)
             else:

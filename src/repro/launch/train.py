@@ -20,10 +20,10 @@ import jax
 from repro import obs
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLM
-from repro.launch.mesh import dims_for, make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import dims_for, local_mesh, make_production_mesh
 from repro.models import build_model
 from repro.optim import AdamWConfig
-from repro.parallel.mesh import ParallelDims, make_mesh
 from repro.train import Trainer
 
 
@@ -94,6 +94,7 @@ def main():
     args = ap.parse_args()
     if args.trace and not args.metrics_dir:
         ap.error("--trace requires --metrics-dir")
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if cfg.moe is not None and (args.pipeline_chunks is not None
@@ -126,12 +127,7 @@ def main():
         mesh = make_production_mesh(multi_pod=args.multi_pod)
         dims = dims_for(cfg, args.multi_pod)
     else:
-        # fold whatever devices exist into (data, model)
-        d = max(1, n_dev // 2) if n_dev > 1 else 1
-        mesh = make_mesh((d, n_dev // d), ("data", "model"))
-        dims = (ParallelDims(ep=("data",), esp=("model",), mp=("model",))
-                if cfg.moe is not None
-                else ParallelDims(dp=("data",), mp=("model",)))
+        mesh, dims = local_mesh(cfg)
 
     if args.metrics_dir:
         obs.configure(args.metrics_dir, meta={

@@ -201,8 +201,28 @@ def _flash_bwd_scan(res, dout, cfg: AttnConfig, q_pos, k_pos, blk):
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
 
 
+def _flash_op(cfg: AttnConfig, kernel, q, k, mesh, dims):
+    """The registry's ``flash_attention`` op for (B, L, H, hd) q and
+    (B, L, K, hd) k/v.  GSPMD cannot partition a Mosaic kernel, so on a
+    multi-device mesh the op runs under ``shard_map``: batch over the
+    batch axes and heads over MP, each only where it divides evenly."""
+    from repro.kernels.registry import get_op
+    from repro.parallel.mesh import axis_size
+    op = get_op("flash_attention", cfg=kernel, causal=cfg.causal,
+                window=cfg.window, scale=cfg.scale)
+    if mesh is None or mesh.devices.size == 1:
+        return op
+    bx, hx = tuple(dims.batch_axes), tuple(dims.mp)
+    b_ax = bx if bx and q.shape[0] % axis_size(mesh, bx) == 0 else None
+    h_ax = hx if hx and k.shape[2] % axis_size(mesh, hx) == 0 else None
+    spec = P(b_ax, None, h_ax, None)
+    return jax.shard_map(op, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
+
+
 def apply_attn(p, cfg: AttnConfig, x, *, positions=None, kv_x=None,
-               kv_positions=None, use_pallas=False, kernel=None):
+               kv_positions=None, use_pallas=False, kernel=None,
+               mesh=None, dims=None):
     """Training/prefill forward. kv_x != None = cross attention.
 
     Kernel-backend selection: ``use_pallas=True`` (legacy flag) or a
@@ -210,7 +230,8 @@ def apply_attn(p, cfg: AttnConfig, x, *, positions=None, kv_x=None,
     through the registry's ``flash_attention`` op; otherwise the jnp paths
     below (full sdpa / online-softmax scan) run — they ARE the reference
     implementation, with masking modes the kernel doesn't cover (chunked
-    local attention, arbitrary position vectors).
+    local attention, arbitrary position vectors).  ``mesh``/``dims``
+    place the kernel per shard on a multi-device mesh.
     """
     B, L, D = x.shape
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -234,7 +255,7 @@ def apply_attn(p, cfg: AttnConfig, x, *, positions=None, kv_x=None,
     if cfg.use_rope and kv_x is None:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, kv_positions, cfg.rope_theta)
-    from repro.kernels.registry import get_op, resolve_backend
+    from repro.kernels.registry import resolve_backend
     want_pallas = use_pallas or (
         kernel is not None and resolve_backend(cfg=kernel) == "pallas")
     # the kernel handles causal/window masks over contiguous positions only
@@ -242,9 +263,7 @@ def apply_attn(p, cfg: AttnConfig, x, *, positions=None, kv_x=None,
     if want_pallas and kernel_ok:
         # KV stays in its native GQA layout — the kernel's index map folds
         # the query-head -> kv-head mapping, no repeat ever hits HBM
-        op = get_op("flash_attention", cfg=kernel, causal=cfg.causal,
-                    window=cfg.window, scale=cfg.scale)
-        out = op(q, k, v)
+        out = _flash_op(cfg, kernel, q, k, mesh, dims)(q, k, v)
     else:
         k = _repeat_kv(k, H // K)
         v = _repeat_kv(v, H // K)
@@ -273,7 +292,8 @@ def init_cache(cfg: AttnConfig, batch, max_len, dtype=jnp.float32):
     }
 
 
-def prefill_attn(p, cfg: AttnConfig, x, cache, lengths, *, kernel=None):
+def prefill_attn(p, cfg: AttnConfig, x, cache, lengths, *, kernel=None,
+                 mesh=None, dims=None):
     """Batched one-shot prefill: whole-prompt self-attention + KV fill.
 
     ``x`` is the (B, L, D) right-padded prompt batch, ``lengths`` the
@@ -318,13 +338,11 @@ def prefill_attn(p, cfg: AttnConfig, x, cache, lengths, *, kernel=None):
         "pos": jnp.where(valid, widx[None, :], -1).astype(jnp.int32),
     }
 
-    from repro.kernels.registry import get_op, resolve_backend
+    from repro.kernels.registry import resolve_backend
     want_pallas = kernel is not None and \
         resolve_backend(cfg=kernel) == "pallas"
     if want_pallas and cfg.chunk is None:
-        op = get_op("flash_attention", cfg=kernel, causal=cfg.causal,
-                    window=cfg.window, scale=cfg.scale)
-        out = op(q, k, v)
+        out = _flash_op(cfg, kernel, q, k, mesh, dims)(q, k, v)
     else:
         kk = _repeat_kv(k, H // K)
         vv = _repeat_kv(v, H // K)

@@ -170,7 +170,7 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, *, mesh, dims,
     if base in ("dense", "moe", "encoder"):
         h = norm(p["norm1"], x)
         a = attn_mod.apply_attn(p["attn"], acfg, h, positions=positions,
-                                kernel=kcfg)
+                                kernel=kcfg, mesh=mesh, dims=dims)
         if cfg.parallel_block:
             f = apply_ffn(p["ffn"], h, cfg.ffn_act)
             # sum the two partial (row-parallel) outputs BEFORE they meet
@@ -202,7 +202,7 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, *, mesh, dims,
         # whisper decoder: self-attn + cross-attn + FFN
         h = norm(p["norm1"], x)
         x = x + attn_mod.apply_attn(p["attn"], acfg, h, positions=positions,
-                                    kernel=kcfg)
+                                    kernel=kcfg, mesh=mesh, dims=dims)
         h = norm(p["norm_x"], x)
         x = x + attn_mod.apply_attn(p["xattn"],
                                     attn_config(cfg, kind, True), h, kv_x=ctx)
@@ -212,7 +212,7 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, *, mesh, dims,
     if base == "hymba":
         h = norm(p["norm1"], x)
         a = attn_mod.apply_attn(p["attn"], acfg, h, positions=positions,
-                                kernel=kcfg)
+                                kernel=kcfg, mesh=mesh, dims=dims)
         s = ssm_mod.apply_mamba(p["mamba"], _mamba_cfg(cfg), h)
         x = x + 0.5 * (norm(p["norm_a"], a)
                        + norm(p["norm_s"], s))
@@ -278,7 +278,8 @@ def prefill_block(p, cfg: ModelConfig, kind: str, x, cache, lengths, *,
 
     h = norm(p["norm1"], x)
     a, c2 = attn_mod.prefill_attn(p["attn"], acfg, h, cache["attn"],
-                                  lengths, kernel=kcfg)
+                                  lengths, kernel=kcfg, mesh=mesh,
+                                  dims=dims)
     new_cache = dict(cache)
     new_cache["attn"] = c2
     if cfg.parallel_block:

@@ -28,6 +28,7 @@ Perfetto): one ``X`` slice per stage laid end to end.
 from __future__ import annotations
 
 import json
+import math
 import time
 from dataclasses import dataclass, field
 from typing import List, Optional
@@ -35,7 +36,6 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import executor
 from repro.core import plan as planlib
 from repro.core.pipeline import UNCHUNKED_OF
@@ -99,7 +99,7 @@ def time_plan_stages(schedule: str, info, mesh, in_specs, args,
         def body(xt, wg, w1, w3_, w2):
             return executor.execute_prefix(plan, xt, wg, w1, w3_, w2,
                                            info, k)
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
             check_vma=False))
 
@@ -147,20 +147,13 @@ def save_chrome_trace(trace: StageTrace, path: str) -> str:
 # --- mesh/operand helpers for standalone harness runs ------------------------
 
 def subset_mesh(shape, names):
-    """A mesh over the *first* ``prod(shape)`` local devices (unlike
-    ``parallel.mesh.make_mesh``, which insists on using all of them) —
-    the audit runs under dryrun's fake-device farm where the full
-    device count is a topology, not a budget."""
-    import numpy as np
-    n = 1
-    for s in shape:
-        n *= int(s)
+    """A mesh over the *first* ``prod(shape)`` local devices (not all of
+    them) — the audit runs under dryrun's fake-device farm where the
+    full device count is a topology, not a budget."""
+    from repro.parallel.mesh import make_mesh
+    n = math.prod(int(s) for s in shape)
     devs = jax.devices()
     if len(devs) < n:
         raise ValueError(f"need {n} devices for mesh {shape}, "
                          f"have {len(devs)}")
-    arr = np.array(devs[:n]).reshape(shape)
-    if compat.AxisType is not None:
-        return jax.sharding.Mesh(
-            arr, names, axis_types=(compat.AxisType.Auto,) * len(names))
-    return jax.sharding.Mesh(arr, names)
+    return make_mesh(shape, names, devices=devs[:n])

@@ -15,18 +15,16 @@ import math
 from dataclasses import dataclass
 
 import jax
-from jax.sharding import Mesh
-
-from repro import compat
+from jax.sharding import AxisType, Mesh
 
 
-def make_mesh(shape, names) -> Mesh:
-    """``jax.make_mesh`` pinned to Auto axis types (GSPMD + shard_map mix).
-
-    Routed through ``repro.compat`` so the same call works on jax 0.4.x
-    (no ``AxisType`` / ``axis_types=``) and 0.5+.
-    """
-    return compat.make_mesh(shape, names)
+def make_mesh(shape, names, devices=None) -> Mesh:
+    """``jax.make_mesh`` pinned to Auto axis types (GSPMD + shard_map mix),
+    over ``devices`` (default: all of them)."""
+    names = tuple(names)
+    return jax.make_mesh(tuple(int(s) for s in shape), names,
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def axis_size(mesh: Mesh, axes) -> int:

@@ -296,6 +296,13 @@ class Trainer:
         self._sched_keys = set(autosched.cache_info())
         return params, opt_state
 
+    def compile(self, params, opt_state, batch):
+        """Compile the (unguarded) step ahead of its first call for these
+        arguments and return the compiled program; ``run`` then executes
+        it.  A later re-jit (placement rebalance) replaces it as usual."""
+        self._step = self._step.lower(params, opt_state, batch).compile()
+        return self._step
+
     def _log_step0(self, metrics):
         # the first step traced the model: any schedule="auto" MoE
         # layers have made their (schedule, n_chunks) decisions now

@@ -109,3 +109,41 @@ class TestMultiDeviceTraining:
             timeout=900)
         assert r.returncode == 0, f"{r.stdout}\n{r.stderr}"
         assert "SHARDED TRAIN OK" in r.stdout
+
+
+ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+
+
+class TestChipSmoke:
+    def test_compile_cache_dir(self, monkeypatch):
+        from repro.launch import compile_cache
+        was = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == was   # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        try:
+            got = compile_cache.enable_compile_cache()
+            assert got == os.path.join(os.path.abspath(ROOT), ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_refuses_cpu(self):
+        env = subprocess_env(1)
+        env["JAX_PLATFORMS"] = "cpu"
+        r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=ROOT,
+                           env=env, capture_output=True, text=True,
+                           timeout=300)
+        assert r.returncode != 0
+        assert "no TPU" in r.stderr
+        assert '"ok"' not in r.stdout
+
+    def test_four_chip_phase_on_cpu_devices(self, helpers_dir):
+        r = subprocess.run(
+            [sys.executable, os.path.join(helpers_dir,
+                                          "run_chip_smoke_four.py")],
+            env=subprocess_env(4), capture_output=True, text=True,
+            timeout=900)
+        assert r.returncode == 0, f"{r.stdout}\n{r.stderr}"
+        assert "FOUR CHIP PHASE OK" in r.stdout
