@@ -1,0 +1,9 @@
+"""ffn.device_ms: device time per step of the dense FFNs, forward and
+backward: the ops under the program's ``ffn`` scope, from the device
+trace, per chip, averaged over chips."""
+
+from bench.harness.scopes import layer_ms
+
+
+def read(run):
+    return layer_ms(run, "ffn")

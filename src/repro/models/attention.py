@@ -231,50 +231,55 @@ def apply_attn(p, cfg: AttnConfig, x, *, positions=None, kv_x=None,
     below (full sdpa / online-softmax scan) run — they ARE the reference
     implementation, with masking modes the kernel doesn't cover (chunked
     local attention, arbitrary position vectors).  ``mesh``/``dims``
-    place the kernel per shard on a multi-device mesh.
+    place the kernel per shard on a multi-device mesh.  Its ops, the
+    projections and the core, carry the ``attn`` scope, which the device
+    trace reads.
     """
-    B, L, D = x.shape
-    H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    src = kv_x if kv_x is not None else x
-    Lk = src.shape[1]
-    q = (x @ p["wq"]).reshape(B, L, H, hd)
-    k = (src @ p["wk"]).reshape(B, Lk, K, hd)
-    v = (src @ p["wv"]).reshape(B, Lk, K, hd)
-    if cfg.qkv_bias:
-        q = q + p["bq"].reshape(H, hd)
-        k = k + p["bk"].reshape(K, hd)
-        v = v + p["bv"].reshape(K, hd)
-    # the Pallas kernel derives positions from block indices, so it is only
-    # valid for the default contiguous-from-zero layout (record before the
-    # arange defaults are filled in)
-    contiguous_pos = positions is None and kv_positions is None
-    if positions is None:
-        positions = jnp.arange(L)
-    if kv_positions is None:
-        kv_positions = jnp.arange(Lk)
-    if cfg.use_rope and kv_x is None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, kv_positions, cfg.rope_theta)
-    from repro.kernels.registry import resolve_backend
-    want_pallas = use_pallas or (
-        kernel is not None and resolve_backend(cfg=kernel) == "pallas")
-    # the kernel handles causal/window masks over contiguous positions only
-    kernel_ok = cfg.chunk is None and kv_x is None and contiguous_pos
-    if want_pallas and kernel_ok:
-        # KV stays in its native GQA layout — the kernel's index map folds
-        # the query-head -> kv-head mapping, no repeat ever hits HBM
-        out = _flash_op(cfg, kernel, q, k, mesh, dims)(q, k, v)
-    else:
-        k = _repeat_kv(k, H // K)
-        v = _repeat_kv(v, H // K)
-        if max(L, Lk) > cfg.flash_threshold:
-            out = sdpa_flash_scan(q, k, v, cfg, positions, kv_positions)
+    with jax.named_scope("attn"):
+        B, L, D = x.shape
+        H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        src = kv_x if kv_x is not None else x
+        Lk = src.shape[1]
+        q = (x @ p["wq"]).reshape(B, L, H, hd)
+        k = (src @ p["wk"]).reshape(B, Lk, K, hd)
+        v = (src @ p["wv"]).reshape(B, Lk, K, hd)
+        if cfg.qkv_bias:
+            q = q + p["bq"].reshape(H, hd)
+            k = k + p["bk"].reshape(K, hd)
+            v = v + p["bv"].reshape(K, hd)
+        # the Pallas kernel derives positions from block indices, so it is
+        # only valid for the default contiguous-from-zero layout (record
+        # before the arange defaults are filled in)
+        contiguous_pos = positions is None and kv_positions is None
+        if positions is None:
+            positions = jnp.arange(L)
+        if kv_positions is None:
+            kv_positions = jnp.arange(Lk)
+        if cfg.use_rope and kv_x is None:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, kv_positions, cfg.rope_theta)
+        from repro.kernels.registry import resolve_backend
+        want_pallas = use_pallas or (
+            kernel is not None and resolve_backend(cfg=kernel) == "pallas")
+        # the kernel handles causal/window masks over contiguous
+        # positions only
+        kernel_ok = cfg.chunk is None and kv_x is None and contiguous_pos
+        if want_pallas and kernel_ok:
+            # KV stays in its native GQA layout — the kernel's index
+            # map folds the query-head -> kv-head mapping, no repeat
+            # ever hits HBM
+            out = _flash_op(cfg, kernel, q, k, mesh, dims)(q, k, v)
         else:
-            bias = _mask_bias(cfg, positions, kv_positions) if (
-                cfg.causal or cfg.window or cfg.chunk) else jnp.zeros(
-                    (L, Lk), jnp.float32)
-            out = sdpa_full(q, k, v, bias, cfg.scale)
-    return out.reshape(B, L, H * hd) @ p["wo"]
+            k = _repeat_kv(k, H // K)
+            v = _repeat_kv(v, H // K)
+            if max(L, Lk) > cfg.flash_threshold:
+                out = sdpa_flash_scan(q, k, v, cfg, positions, kv_positions)
+            else:
+                bias = _mask_bias(cfg, positions, kv_positions) if (
+                    cfg.causal or cfg.window or cfg.chunk) else jnp.zeros(
+                        (L, Lk), jnp.float32)
+                out = sdpa_full(q, k, v, bias, cfg.scale)
+        return out.reshape(B, L, H * hd) @ p["wo"]
 
 
 # --- decode with KV cache -----------------------------------------------------

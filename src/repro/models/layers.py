@@ -41,15 +41,18 @@ def norm_specs(norm_type="rmsnorm"):
 def apply_norm(p, x, eps=1e-5, kernel=None):
     """LayerNorm (bias present) stays inline jnp; RMSNorm routes through the
     kernel registry (``rmsnorm`` op) so the backend follows ``kernel`` —
-    the ref oracle is numerically identical to the historical inline code."""
-    if "bias" in p:
-        xf = x.astype(jnp.float32)
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.var(xf, axis=-1, keepdims=True)
-        out = (xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
-        return out.astype(x.dtype)
-    op = get_op("rmsnorm", cfg=kernel, eps=eps)
-    return op(x.reshape(-1, x.shape[-1]), p["scale"]).reshape(x.shape)
+    the ref oracle is numerically identical to the historical inline code.
+    Its ops carry the ``norm`` scope, which the device trace reads."""
+    with jax.named_scope("norm"):
+        if "bias" in p:
+            xf = x.astype(jnp.float32)
+            mu = jnp.mean(xf, axis=-1, keepdims=True)
+            var = jnp.var(xf, axis=-1, keepdims=True)
+            out = (xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] \
+                + p["bias"]
+            return out.astype(x.dtype)
+        op = get_op("rmsnorm", cfg=kernel, eps=eps)
+        return op(x.reshape(-1, x.shape[-1]), p["scale"]).reshape(x.shape)
 
 
 # --- rotary embeddings --------------------------------------------------------
@@ -109,19 +112,21 @@ def ffn_specs(mesh, mp_axes, d_ff, glu=True, bias=False):
 
 
 def apply_ffn(p, x, act="silu"):
+    """The dense FFN, under the ``ffn`` scope."""
     actf = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
             "relu": jax.nn.relu}[act]
-    h = x @ p["w_in"]
-    if "b_in" in p:
-        h = h + p["b_in"]
-    if "w_gate" in p:
-        h = actf(x @ p["w_gate"]) * h
-    else:
-        h = actf(h)
-    out = h @ p["w_out"]
-    if "b_out" in p:
-        out = out + p["b_out"]
-    return out
+    with jax.named_scope("ffn"):
+        h = x @ p["w_in"]
+        if "b_in" in p:
+            h = h + p["b_in"]
+        if "w_gate" in p:
+            h = actf(x @ p["w_gate"]) * h
+        else:
+            h = actf(h)
+        out = h @ p["w_out"]
+        if "b_out" in p:
+            out = out + p["b_out"]
+        return out
 
 
 # --- embeddings ---------------------------------------------------------------

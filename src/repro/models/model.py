@@ -180,39 +180,46 @@ class Model:
         # command-r train_4k otherwise — see EXPERIMENTS.md §Perf).
         hidden, aux = self._backbone(params, batch, mesh=mesh,
                                      dims=dims, schedule=schedule)
-        logits_fn_input = hidden
-        b_local = max(B // max(_axis_size(mesh, dims.batch_axes), 1), 1)
-        chunk = L
-        while b_local * chunk * cfg.vocab_size > (1 << 28) and chunk % 2 == 0:
-            chunk //= 2
-        n_chunks = L // chunk if L % chunk == 0 else 1
-        if n_chunks <= 1:
-            logits = self._head(params, logits_fn_input)
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            ll = jnp.take_along_axis(logp, labels[..., None],
-                                     axis=-1)[..., 0]
-            mask = (labels >= 0).astype(jnp.float32)
-            ce = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-        else:
-            def chunk_ce(x_c, y_c):
-                logits = self._head(params, x_c)
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
-                ll = jnp.take_along_axis(logp, y_c[..., None], -1)[..., 0]
-                m = (y_c >= 0).astype(jnp.float32)
-                return jnp.sum(-ll * m), jnp.sum(m)
+        # the LM head and cross-entropy, both paths, under the ``head``
+        # scope the device trace reads
+        with jax.named_scope("head"):
+            logits_fn_input = hidden
+            b_local = max(B // max(_axis_size(mesh, dims.batch_axes), 1), 1)
+            chunk = L
+            while b_local * chunk * cfg.vocab_size > (1 << 28) \
+                    and chunk % 2 == 0:
+                chunk //= 2
+            n_chunks = L // chunk if L % chunk == 0 else 1
+            if n_chunks <= 1:
+                logits = self._head(params, logits_fn_input)
+                logp = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                          axis=-1)
+                ll = jnp.take_along_axis(logp, labels[..., None],
+                                         axis=-1)[..., 0]
+                mask = (labels >= 0).astype(jnp.float32)
+                ce = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+            else:
+                def chunk_ce(x_c, y_c):
+                    logits = self._head(params, x_c)
+                    logp = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                              -1)
+                    ll = jnp.take_along_axis(logp, y_c[..., None],
+                                             -1)[..., 0]
+                    m = (y_c >= 0).astype(jnp.float32)
+                    return jnp.sum(-ll * m), jnp.sum(m)
 
-            def step(carry, idx):
-                x_c = lax.dynamic_slice_in_dim(logits_fn_input,
-                                               idx * chunk, chunk, 1)
-                y_c = lax.dynamic_slice_in_dim(labels, idx * chunk,
-                                               chunk, 1)
-                s, n = jax.checkpoint(chunk_ce)(x_c, y_c)
-                return (carry[0] + s, carry[1] + n), None
+                def step(carry, idx):
+                    x_c = lax.dynamic_slice_in_dim(logits_fn_input,
+                                                   idx * chunk, chunk, 1)
+                    y_c = lax.dynamic_slice_in_dim(labels, idx * chunk,
+                                                   chunk, 1)
+                    s, n = jax.checkpoint(chunk_ce)(x_c, y_c)
+                    return (carry[0] + s, carry[1] + n), None
 
-            (tot, n), _ = lax.scan(step, (jnp.float32(0.0),
-                                          jnp.float32(0.0)),
-                                   jnp.arange(n_chunks))
-            ce = tot / jnp.maximum(n, 1.0)
+                (tot, n), _ = lax.scan(step, (jnp.float32(0.0),
+                                              jnp.float32(0.0)),
+                                       jnp.arange(n_chunks))
+                ce = tot / jnp.maximum(n, 1.0)
         total = ce + aux["aux_loss"]
         return total, {"ce": ce, "aux": aux["aux_loss"],
                        "ppl_proxy": jnp.exp(jnp.minimum(ce, 20.0)),

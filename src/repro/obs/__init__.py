@@ -19,6 +19,12 @@ One small layer, three pieces:
     them against ``PerfModel.t_plan_stages`` predictions into a
     predicted-vs-measured report (``launch/dryrun.py --audit``).
 
+Set-up phases (:func:`phase`) time the host work before training —
+building the state, lowering and compiling the step, a checkpoint save
+— on the host clock, keep the last ``PHASES_MAX`` of them in memory
+(:func:`phases`), and mark each on the profiler's host plane with a
+``jax.profiler.TraceAnnotation`` of the same name.
+
 Emission is opt-in and cheap when off: ``emit(...)`` with no sink
 installed is a single attribute test, and nothing here runs inside a
 jitted program — runtime events arrive through the same host-side
@@ -37,7 +43,12 @@ Two context planes keep events attributable:
 
 from __future__ import annotations
 
+import collections
+import time
 from contextlib import contextmanager
+from typing import NamedTuple, Optional
+
+import jax
 
 from repro.obs.registry import (Counter, Gauge, Histogram,  # noqa: F401
                                 Registry, quantile)
@@ -46,6 +57,9 @@ from repro.obs.sink import JsonlSink  # noqa: F401
 _SINK = None            # process-wide JsonlSink (None = telemetry off)
 _RUNTIME_CTX: dict = {}  # host-side event context (e.g. step=)
 _TRACE_CTX: dict = {}    # trace-time context (e.g. moe_layer=)
+PHASES_MAX = 1024        # phases kept in memory, newest last
+_PHASES: collections.deque = collections.deque(maxlen=PHASES_MAX)
+_OPEN_PHASES: list = []  # names of the phases entered and not yet left
 
 
 def configure(metrics_dir: str, meta=None, **sink_kw) -> JsonlSink:
@@ -123,3 +137,38 @@ def trace_tag(**fields):
                 _TRACE_CTX.pop(k, None)
             else:
                 _TRACE_CTX[k] = v
+
+
+class Phase(NamedTuple):
+    """One finished :func:`phase`: ``time.perf_counter_ns`` at entry and
+    exit, and the name of the phase it ran inside (None at top level)."""
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: Optional[str]
+
+
+@contextmanager
+def phase(name: str, **args):
+    """Time a block of host work as the phase ``name``: a
+    ``jax.profiler.TraceAnnotation`` while it runs, a :class:`Phase` in
+    :func:`phases` when it ends, and a ``phase`` event (with ``args``)
+    through the sink when one is installed.  Adds no synchronisation:
+    device work the block starts is timed by whatever waits for it."""
+    parent = _OPEN_PHASES[-1] if _OPEN_PHASES else None
+    _OPEN_PHASES.append(name)
+    start = time.perf_counter_ns()
+    try:
+        with jax.profiler.TraceAnnotation(name, **args):
+            yield
+    finally:
+        end = time.perf_counter_ns()
+        _OPEN_PHASES.pop()
+        _PHASES.append(Phase(name, start, end, parent))
+        emit("phase", name=name, seconds=(end - start) / 1e9,
+             parent=parent, **args)
+
+
+def phases() -> list:
+    """The finished phases, oldest first (at most ``PHASES_MAX``)."""
+    return list(_PHASES)

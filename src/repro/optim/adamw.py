@@ -98,49 +98,52 @@ def adamw_update(params, grads, state, cfg: AdamWConfig,
     pass over the trees (a separate post-hoc tree-select measurably does
     not fuse).  A masked-out step leaves params, moments, and the step
     counter bit-identical to never having run; the combined mask comes
-    back in the metrics as ``"finite"``."""
-    step = state["step"] + 1
-    lr = cosine_schedule(cfg, step) * lr_scale
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
-    if finite is not None:
-        finite = finite & jnp.isfinite(gnorm)
-
-    if decay_mask is None:
-        decay_mask = jax.tree.map(lambda p: p.ndim >= 2, params)
-
-    b1c = 1 - cfg.beta1 ** step.astype(jnp.float32)
-    b2c = 1 - cfg.beta2 ** step.astype(jnp.float32)
-
-    def upd(p, g, mu0, nu0, wd):
-        g = g.astype(jnp.float32) * scale
-        mu = cfg.beta1 * mu0 + (1 - cfg.beta1) * g
-        nu = cfg.beta2 * nu0 + (1 - cfg.beta2) * jnp.square(g)
-        mhat = mu / b1c
-        nhat = nu / b2c
-        delta = mhat / (jnp.sqrt(nhat) + cfg.eps)
-        if wd:
-            delta = delta + cfg.weight_decay * p.astype(jnp.float32)
-        p2 = (p.astype(jnp.float32) - lr * delta).astype(p.dtype)
+    back in the metrics as ``"finite"``.  The whole update, the global
+    norm included, carries the ``adamw`` scope, which the device trace
+    reads."""
+    with jax.named_scope("adamw"):
+        step = state["step"] + 1
+        lr = cosine_schedule(cfg, step) * lr_scale
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
         if finite is not None:
-            p2 = jnp.where(finite, p2, p)
-            mu = jnp.where(finite, mu, mu0)
-            nu = jnp.where(finite, nu, nu0)
-        return p2, mu, nu
+            finite = finite & jnp.isfinite(gnorm)
 
-    flat_p, tdef = jax.tree.flatten(params)
-    flat_g = tdef.flatten_up_to(grads)
-    flat_mu = tdef.flatten_up_to(state["mu"])
-    flat_nu = tdef.flatten_up_to(state["nu"])
-    flat_wd = tdef.flatten_up_to(decay_mask)
-    new = [upd(p, g, mu, nu, wd) for p, g, mu, nu, wd
-           in zip(flat_p, flat_g, flat_mu, flat_nu, flat_wd)]
-    new_p = tdef.unflatten([t[0] for t in new])
-    new_state = {"mu": tdef.unflatten([t[1] for t in new]),
-                 "nu": tdef.unflatten([t[2] for t in new]),
-                 "step": step if finite is None
-                 else jnp.where(finite, step, state["step"])}
-    om = {"grad_norm": gnorm, "lr": lr}
-    if finite is not None:
-        om["finite"] = finite
-    return new_p, new_state, om
+        if decay_mask is None:
+            decay_mask = jax.tree.map(lambda p: p.ndim >= 2, params)
+
+        b1c = 1 - cfg.beta1 ** step.astype(jnp.float32)
+        b2c = 1 - cfg.beta2 ** step.astype(jnp.float32)
+
+        def upd(p, g, mu0, nu0, wd):
+            g = g.astype(jnp.float32) * scale
+            mu = cfg.beta1 * mu0 + (1 - cfg.beta1) * g
+            nu = cfg.beta2 * nu0 + (1 - cfg.beta2) * jnp.square(g)
+            mhat = mu / b1c
+            nhat = nu / b2c
+            delta = mhat / (jnp.sqrt(nhat) + cfg.eps)
+            if wd:
+                delta = delta + cfg.weight_decay * p.astype(jnp.float32)
+            p2 = (p.astype(jnp.float32) - lr * delta).astype(p.dtype)
+            if finite is not None:
+                p2 = jnp.where(finite, p2, p)
+                mu = jnp.where(finite, mu, mu0)
+                nu = jnp.where(finite, nu, nu0)
+            return p2, mu, nu
+
+        flat_p, tdef = jax.tree.flatten(params)
+        flat_g = tdef.flatten_up_to(grads)
+        flat_mu = tdef.flatten_up_to(state["mu"])
+        flat_nu = tdef.flatten_up_to(state["nu"])
+        flat_wd = tdef.flatten_up_to(decay_mask)
+        new = [upd(p, g, mu, nu, wd) for p, g, mu, nu, wd
+               in zip(flat_p, flat_g, flat_mu, flat_nu, flat_wd)]
+        new_p = tdef.unflatten([t[0] for t in new])
+        new_state = {"mu": tdef.unflatten([t[1] for t in new]),
+                     "nu": tdef.unflatten([t[2] for t in new]),
+                     "step": step if finite is None
+                     else jnp.where(finite, step, state["step"])}
+        om = {"grad_norm": gnorm, "lr": lr}
+        if finite is not None:
+            om["finite"] = finite
+        return new_p, new_state, om

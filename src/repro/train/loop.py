@@ -264,43 +264,51 @@ class Trainer:
     rebalance_margin: float = 1.05        # modeled win required to swap
 
     def setup(self, key):
-        m, mesh, dims = self.model, self.mesh, self.dims
-        pspecs = m.specs(mesh, dims)
-        p_sh = named_tree(mesh, pspecs)
-        o_sh = named_tree(mesh, opt_state_specs(pspecs))
-        params = jax.jit(m.init, out_shardings=p_sh)(key)
-        opt_state = jax.jit(adamw_init, out_shardings=o_sh)(params)
-        self._p_sh, self._o_sh = p_sh, o_sh
-        from repro.core.placement import LoadEMA
-        self.load_ema = LoadEMA()
-        if self.guards is None:
-            self._step_fn = make_train_step(m, mesh, dims, self.opt_cfg,
-                                            self.schedule)
-            self._step = jax.jit(self._step_fn, donate_argnums=(0, 1))
-        else:
-            from repro.runtime import guards as guardlib
-            self.guard_state = guardlib.GuardState(cfg=self.guards)
-            guardlib.reset_fp8_counter()
-            # monitor installed BEFORE the jit below traces, so fp8
-            # encodes in this step's program carry the saturation counter
-            guardlib.enable_fp8_monitor()
-            if self.faults:
-                factor = self.faults.fp8_sat_factor()
-                if factor:
-                    from repro.core import collectives
-                    collectives.set_fp8_sat_injection(factor)
-            self._step_fn = make_guarded_train_step(
-                m, mesh, dims, self.opt_cfg, self.schedule)
-            self._step = jax.jit(self._step_fn, donate_argnums=(0, 1))
-        from repro.core import autosched
-        self._sched_keys = set(autosched.cache_info())
-        return params, opt_state
+        """Make the parameters and the optimizer state on the mesh from
+        ``key`` and build the step; the phase ``setup.init``."""
+        with obs.phase("setup.init"):
+            m, mesh, dims = self.model, self.mesh, self.dims
+            pspecs = m.specs(mesh, dims)
+            p_sh = named_tree(mesh, pspecs)
+            o_sh = named_tree(mesh, opt_state_specs(pspecs))
+            params = jax.jit(m.init, out_shardings=p_sh)(key)
+            opt_state = jax.jit(adamw_init, out_shardings=o_sh)(params)
+            self._p_sh, self._o_sh = p_sh, o_sh
+            from repro.core.placement import LoadEMA
+            self.load_ema = LoadEMA()
+            if self.guards is None:
+                self._step_fn = make_train_step(m, mesh, dims, self.opt_cfg,
+                                                self.schedule)
+                self._step = jax.jit(self._step_fn, donate_argnums=(0, 1))
+            else:
+                from repro.runtime import guards as guardlib
+                self.guard_state = guardlib.GuardState(cfg=self.guards)
+                guardlib.reset_fp8_counter()
+                # monitor installed BEFORE the jit below traces, so fp8
+                # encodes in this step's program carry the saturation counter
+                guardlib.enable_fp8_monitor()
+                if self.faults:
+                    factor = self.faults.fp8_sat_factor()
+                    if factor:
+                        from repro.core import collectives
+                        collectives.set_fp8_sat_injection(factor)
+                self._step_fn = make_guarded_train_step(
+                    m, mesh, dims, self.opt_cfg, self.schedule)
+                self._step = jax.jit(self._step_fn, donate_argnums=(0, 1))
+            from repro.core import autosched
+            self._sched_keys = set(autosched.cache_info())
+            return params, opt_state
 
     def compile(self, params, opt_state, batch):
         """Compile the (unguarded) step ahead of its first call for these
         arguments and return the compiled program; ``run`` then executes
-        it.  A later re-jit (placement rebalance) replaces it as usual."""
-        self._step = self._step.lower(params, opt_state, batch).compile()
+        it.  A later re-jit (placement rebalance) replaces it as usual.
+        The phases ``setup.lower`` and ``setup.compile`` (the backend's
+        compile, or the read of the persistent cache) time the two."""
+        with obs.phase("setup.lower"):
+            lowered = self._step.lower(params, opt_state, batch)
+        with obs.phase("setup.compile"):
+            self._step = lowered.compile()
         return self._step
 
     def _log_step0(self, metrics):
@@ -314,17 +322,20 @@ class Trainer:
         el = metrics.get("expert_load")
         if el is not None and getattr(el, "ndim", 0) == 1 \
                 and el.shape[-1]:
-            vals = " ".join(f"{float(c):.0f}"
-                            for c in jax.device_get(el))
+            with jax.profiler.TraceAnnotation("train.expert_load_read"):
+                el = jax.device_get(el)
+            vals = " ".join(f"{float(c):.0f}" for c in el)
             print(f"expert load (routed rows/expert, all layers): "
                   f"[{vals}]", flush=True)
 
     def _track_load(self, metrics):
         """Fold this step's per-expert routed-row counts into the
-        rolling load EMA (host-side numpy; a no-op for dense models)."""
+        rolling load EMA (host-side numpy; a no-op for dense models).
+        The read waits for the step: the span ``train.expert_load_read``."""
         el = metrics.get("expert_load")
         if el is not None and getattr(el, "ndim", 0) == 1 and el.shape[-1]:
-            el = jax.device_get(el)
+            with jax.profiler.TraceAnnotation("train.expert_load_read"):
+                el = jax.device_get(el)
             if float(el.sum()) > 0:      # all-zero = no routing signal
                 self.load_ema.update(el)
 
@@ -339,6 +350,26 @@ class Trainer:
             obs.emit("expert_load", step=m.get("step"),
                      load=[round(float(v), 3)
                            for v in self.load_ema.value()])
+
+    def _log(self, step, metrics, t0, **extra) -> dict:
+        """One history row: the step's scalar metrics, read in the span
+        ``train.log`` (the reads wait for the step), the wall time since
+        ``t0``, ``extra`` and the load imbalance; emitted and printed."""
+        with jax.profiler.TraceAnnotation("train.log"):
+            # vector metrics (e.g. expert_load) are step-0 diagnostics,
+            # not per-step scalars — keep the history float-only
+            m = {k: float(v) for k, v in metrics.items()
+                 if getattr(v, "ndim", 0) == 0}
+        m["step"] = step
+        m["wall_s"] = time.perf_counter() - t0
+        m.update(extra)
+        if self.load_ema.ready:
+            m["load_imbalance"] = self.load_ema.imbalance()
+        self._emit_train_step(m)
+        print(f"step {step:5d}  loss {m['loss']:.4f}  "
+              f"ce {m['ce']:.4f}  gnorm {m['grad_norm']:.3f}  "
+              f"lr {m['lr']:.2e}", flush=True)
+        return m
 
     def _maybe_rebalance(self, step):
         """Every ``rebalance_every`` steps, ask autosched whether a
@@ -378,33 +409,27 @@ class Trainer:
         bx = tuple(self.dims.batch_axes)
         t0 = time.perf_counter()
         for step in range(n_steps):
-            if obs.enabled():
-                obs.set_context(step=step)
-            batch = data.sharded_batch(step, self.mesh, bx)
-            params, opt_state, metrics = self._step(params, opt_state, batch)
-            if step == 0:
-                self._log_step0(metrics)
-            self._track_load(metrics)
-            self._maybe_rebalance(step)
-            if step % log_every == 0 or step == n_steps - 1:
-                # vector metrics (e.g. expert_load) are step-0 diagnostics,
-                # not per-step scalars — keep the history float-only
-                m = {k: float(v) for k, v in metrics.items()
-                     if getattr(v, "ndim", 0) == 0}
-                m["step"] = step
-                m["wall_s"] = time.perf_counter() - t0
-                if self.load_ema.ready:
-                    m["load_imbalance"] = self.load_ema.imbalance()
-                history.append(m)
-                self._emit_train_step(m)
-                print(f"step {step:5d}  loss {m['loss']:.4f}  "
-                      f"ce {m['ce']:.4f}  gnorm {m['grad_norm']:.3f}  "
-                      f"lr {m['lr']:.2e}", flush=True)
-            if ckpt_every and self.ckpt_path and step and \
-                    step % ckpt_every == 0:
-                from repro.checkpoint import save_checkpoint
-                save_checkpoint(self.ckpt_path,
-                                {"params": params, "opt": opt_state}, step)
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                if obs.enabled():
+                    obs.set_context(step=step)
+                with jax.profiler.TraceAnnotation("train.input"):
+                    batch = data.sharded_batch(step, self.mesh, bx)
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    params, opt_state, metrics = self._step(params, opt_state,
+                                                            batch)
+                if step == 0:
+                    self._log_step0(metrics)
+                self._track_load(metrics)
+                self._maybe_rebalance(step)
+                if step % log_every == 0 or step == n_steps - 1:
+                    history.append(self._log(step, metrics, t0))
+                if ckpt_every and self.ckpt_path and step and \
+                        step % ckpt_every == 0:
+                    from repro.checkpoint import save_checkpoint
+                    with obs.phase("train.checkpoint", step=step):
+                        save_checkpoint(self.ckpt_path,
+                                        {"params": params, "opt": opt_state},
+                                        step)
         return params, opt_state, history
 
     def _run_guarded(self, params, opt_state, data, n_steps: int,
@@ -425,77 +450,74 @@ class Trainer:
                 "params": self._p_sh, "opt_state": self._o_sh})
             # anchor before step 0: a streak in the first interval must
             # have somewhere to roll back to
-            mgr.snapshot(params, opt_state, 0)
+            with obs.phase("train.checkpoint", step=0):
+                mgr.snapshot(params, opt_state, 0)
 
         history = []
         bx = tuple(self.dims.batch_axes)
         t0 = time.perf_counter()
         for step in range(n_steps):
-            if obs.enabled():
-                obs.set_context(step=step)
-            batch = data.sharded_batch(step, self.mesh, bx)
-            gf = self.faults.grad_fault(step) if self.faults else 0.0
-            # donated-in params/opt_state come back as the OLD values on a
-            # skipped step (the jitted where-select), so unconditional
-            # reassignment is correct either way
-            params, opt_state, metrics = self._step(
-                params, opt_state, batch, state.lr_scale, gf)
-            loss = float(metrics["loss"])
-            action = state.observe(step, loss, bool(metrics["nonfinite"]))
-            if step == 0:
-                self._log_step0(metrics)
-            self._track_load(metrics)
-            self._maybe_rebalance(step)
-            if action == guardlib.ROLLBACK:
-                res = mgr.rollback(step) if mgr is not None else None
-                if res is None:
-                    # nothing restorable: limp on with the backed-off LR
-                    state.record_rollback(step, None)
-                    obs.emit("guard_rollback", restored_step=None,
-                             loss=loss)
-                else:
-                    params, opt_state, rstep = res
-                    state.record_rollback(step, rstep)
-                    obs.emit("guard_rollback", restored_step=rstep,
-                             loss=loss)
-                    print(f"step {step:5d}  ROLLBACK -> re-anchored to "
-                          f"checkpoint step {rstep}", flush=True)
-            elif action == guardlib.SKIP:
-                obs.emit("guard_skip", streak=state.streak,
-                         lr_scale=state.lr_scale)
-                print(f"step {step:5d}  SKIPPED (non-finite, streak "
-                      f"{state.streak}, lr_scale {state.lr_scale:.3g})",
-                      flush=True)
-            if state.check_fp8():
-                # fp8 wire overflow: clamp every wire decision up to the
-                # fallback dtype and re-jit — the retrace re-consults
-                # autosched.decide under the new ceiling (cheap plan
-                # swap; params/opt state untouched)
-                autosched.set_wire_ceiling(state.cfg.fp8_fallback)
-                n = autosched.invalidate("fp8 wire overflow fallback")
-                self._step = jax.jit(self._step_fn, donate_argnums=(0, 1))
-                obs.emit("fp8_fallback",
-                         sat_rate=guardlib.fp8_sat_rate(),
-                         wire=state.cfg.fp8_fallback, invalidated=n)
-                print(f"fp8 wire overflow (sat rate "
-                      f"{guardlib.fp8_sat_rate():.2e}): falling back to "
-                      f"{state.cfg.fp8_fallback} wire "
-                      f"({n} cached decisions invalidated)", flush=True)
-            if step % log_every == 0 or step == n_steps - 1:
-                m = {k: float(v) for k, v in metrics.items()
-                     if getattr(v, "ndim", 0) == 0}
-                m["step"] = step
-                m["wall_s"] = time.perf_counter() - t0
-                m["lr_scale"] = state.lr_scale
-                if self.load_ema.ready:
-                    m["load_imbalance"] = self.load_ema.imbalance()
-                history.append(m)
-                self._emit_train_step(m)
-                print(f"step {step:5d}  loss {m['loss']:.4f}  "
-                      f"ce {m['ce']:.4f}  gnorm {m['grad_norm']:.3f}  "
-                      f"lr {m['lr']:.2e}", flush=True)
-            if mgr is not None and ckpt_every and step and \
-                    step % ckpt_every == 0 and action == guardlib.OK:
-                mgr.snapshot(params, opt_state, step)
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                if obs.enabled():
+                    obs.set_context(step=step)
+                with jax.profiler.TraceAnnotation("train.input"):
+                    batch = data.sharded_batch(step, self.mesh, bx)
+                gf = self.faults.grad_fault(step) if self.faults else 0.0
+                # donated-in params/opt_state come back as the OLD values on a
+                # skipped step (the jitted where-select), so unconditional
+                # reassignment is correct either way
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    params, opt_state, metrics = self._step(
+                        params, opt_state, batch, state.lr_scale, gf)
+                with jax.profiler.TraceAnnotation("train.guard_read"):
+                    loss = float(metrics["loss"])
+                    nonfinite = bool(metrics["nonfinite"])
+                action = state.observe(step, loss, nonfinite)
+                if step == 0:
+                    self._log_step0(metrics)
+                self._track_load(metrics)
+                self._maybe_rebalance(step)
+                if action == guardlib.ROLLBACK:
+                    res = mgr.rollback(step) if mgr is not None else None
+                    if res is None:
+                        # nothing restorable: limp on with the backed-off LR
+                        state.record_rollback(step, None)
+                        obs.emit("guard_rollback", restored_step=None,
+                                 loss=loss)
+                    else:
+                        params, opt_state, rstep = res
+                        state.record_rollback(step, rstep)
+                        obs.emit("guard_rollback", restored_step=rstep,
+                                 loss=loss)
+                        print(f"step {step:5d}  ROLLBACK -> re-anchored to "
+                              f"checkpoint step {rstep}", flush=True)
+                elif action == guardlib.SKIP:
+                    obs.emit("guard_skip", streak=state.streak,
+                             lr_scale=state.lr_scale)
+                    print(f"step {step:5d}  SKIPPED (non-finite, streak "
+                          f"{state.streak}, lr_scale {state.lr_scale:.3g})",
+                          flush=True)
+                if state.check_fp8():
+                    # fp8 wire overflow: clamp every wire decision up to the
+                    # fallback dtype and re-jit — the retrace re-consults
+                    # autosched.decide under the new ceiling (cheap plan
+                    # swap; params/opt state untouched)
+                    autosched.set_wire_ceiling(state.cfg.fp8_fallback)
+                    n = autosched.invalidate("fp8 wire overflow fallback")
+                    self._step = jax.jit(self._step_fn, donate_argnums=(0, 1))
+                    obs.emit("fp8_fallback",
+                             sat_rate=guardlib.fp8_sat_rate(),
+                             wire=state.cfg.fp8_fallback, invalidated=n)
+                    print(f"fp8 wire overflow (sat rate "
+                          f"{guardlib.fp8_sat_rate():.2e}): falling back to "
+                          f"{state.cfg.fp8_fallback} wire "
+                          f"({n} cached decisions invalidated)", flush=True)
+                if step % log_every == 0 or step == n_steps - 1:
+                    history.append(self._log(step, metrics, t0,
+                                             lr_scale=state.lr_scale))
+                if mgr is not None and ckpt_every and step and \
+                        step % ckpt_every == 0 and action == guardlib.OK:
+                    with obs.phase("train.checkpoint", step=step):
+                        mgr.snapshot(params, opt_state, step)
         print(state.summary(), flush=True)
         return params, opt_state, history
