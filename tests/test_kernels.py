@@ -64,6 +64,75 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
                                    atol=3e-5, rtol=3e-5)
 
+    @pytest.mark.parametrize("B,L,H,K,hd,causal,window,grad", [
+        (1, 256, 12, 12, 64, True, None, False),   # the cells' heads
+        (2, 128, 8, 4, 32, True, None, True),      # rep 2, one tile
+        (1, 1024, 4, 1, 32, True, None, False),    # rep 4, 8 x 8 tiles
+        (1, 256, 6, 6, 32, True, 64, True),        # four-chip shard
+        (2, 192, 3, 3, 32, False, None, False),    # shard, L off 128
+        (1, 320, 8, 2, 64, True, 100, True),       # rep 4, window, L off
+    ])
+    def test_heads_per_step(self, B, L, H, K, hd, causal, window, grad):
+        """Several query heads a grid step match the oracle, forward
+        and through the op's ref-recompute VJP."""
+        from repro.kernels import flash_attention as fa
+        assert fa.heads_per_step(H, K, hd, 128, 128) > 1
+        ks = jax.random.split(jax.random.PRNGKey(3), 4)
+        q = jax.random.normal(ks[0], (B, L, H, hd))
+        k = jax.random.normal(ks[1], (B, L, K, hd))
+        v = jax.random.normal(ks[2], (B, L, K, hd))
+        w = jax.random.normal(ks[3], (B, L, H, hd))
+
+        def kernel(q, k, v):
+            return ops.flash_attention(q, k, v, causal=causal,
+                                       window=window)
+
+        def oracle(q, k, v):
+            kk, vv = jnp.repeat(k, H // K, 2), jnp.repeat(v, H // K, 2)
+            return ref.flash_attention_ref(q, kk, vv, causal=causal,
+                                           window=window)
+
+        np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                                   np.asarray(oracle(q, k, v)),
+                                   atol=2e-5, rtol=2e-5)
+        if grad:
+            g = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))
+            g_ref = jax.grad(lambda *a: jnp.sum(oracle(*a) * w), (0, 1, 2))
+            for a, b in zip(g(q, k, v), g_ref(q, k, v)):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           atol=2e-5, rtol=2e-5)
+
+    def test_heads_per_step_rule(self, tmp_path):
+        """``hb`` divides H, is a multiple of rep and fits the VMEM
+        budget; the cells' shapes take all 12 heads a step, and the
+        ``kernel.grid`` event says so."""
+        from repro import obs
+        from repro.kernels import flash_attention as fa
+        from repro.obs.sink import read_events
+        for H, K, hd in [(12, 12, 64), (6, 6, 64), (3, 3, 64), (32, 8, 128),
+                         (32, 4, 128), (64, 8, 128), (40, 8, 128),
+                         (16, 16, 256), (8, 1, 512)]:
+            hb = fa.heads_per_step(H, K, hd, 128, 128)
+            assert H % hb == 0 and hb % (H // K) == 0, (H, K, hd, hb)
+            assert (fa.step_vmem_bytes(hb, H // K, hd, 128, 128, 4)
+                    <= fa.VMEM_BUDGET or hb == H // K), (H, K, hd, hb)
+        assert fa.heads_per_step(12, 12, 64, 128, 128) == 12
+
+        sink = obs.configure(str(tmp_path))
+        try:
+            for shape in [(16, 1024, 12, 64), (128, 128, 12, 64)]:
+                x = jax.ShapeDtypeStruct(shape, jnp.float32)
+                jax.eval_shape(fa.flash_attention, x, x, x)
+            obs.flush()
+            ev = [e for e in read_events(sink.paths)
+                  if e["event"] == "kernel.grid"]
+        finally:
+            obs.close()
+        assert [e["kernel"] for e in ev] == ["flash_attention"] * 2
+        assert [e["heads_per_step"] for e in ev] == [12, 12]
+        assert [e["grid_steps"] for e in ev] == [16 * 8 * 8, 128]
+        assert [e["in_band_steps"] for e in ev] == [16 * 36, 128]
+
 
 class TestExpertFFN:
     @pytest.mark.parametrize("E,T,M,F", [

@@ -6,7 +6,8 @@ CPU cannot see what Mosaic refuses — block shapes off the (8, 128) tile,
 more VMEM than a kernel may hold, a kernel GSPMD would have to partition
 — so each kernel is compiled here at gpt2-moe's published widths
 (M=768, expert FFN 3072 with GELU, E=8, top-2, capacity factor 1.2,
-8192 tokens per step; attention B=8, L=1024, H=12, hd=64), rmsnorm at
+8192 tokens per step; attention B=8, L=1024, H=12, hd=64, and both
+cells' and a GQA shape with several heads a grid step), rmsnorm at
 qwen3's 2048.  Nothing runs: a pass says the chip's compiler takes the
 kernel, not that it computes the right thing (the interpret-mode parity
 tests say that).
@@ -92,6 +93,22 @@ def test_kernel_compiles_for_v5e(op, one_chip, no_compile_cache):
     static, args = _cases(one_chip)[op]
     compiled = jax.jit(get_op(op, cfg=PALLAS, **static)).lower(
         *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B,L,H,K,hd", [
+    (16, 1024, 12, 12, 64),    # gpt2-moe.train-s1024
+    (128, 128, 12, 12, 64),    # bert-moe.train-s128
+    (4, 1024, 32, 8, 128),     # GQA, rep 4
+])
+def test_flash_attention_heads_per_step_compiles_for_v5e(
+        B, L, H, K, hd, one_chip, no_compile_cache):
+    """Several heads a grid step: the blocks, scratch and score tiles
+    that ``heads_per_step`` sizes must fit Mosaic's VMEM and tiling."""
+    q = jax.ShapeDtypeStruct((B, L, H, hd), jnp.float32, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, L, K, hd), jnp.float32, sharding=one_chip)
+    op = get_op("flash_attention", cfg=PALLAS, causal=True)
+    compiled = jax.jit(op).lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
