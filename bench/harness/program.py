@@ -11,13 +11,12 @@ from __future__ import annotations
 
 import functools
 import re
-from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from bench.harness.spec import SpecError
+from bench.harness.spec import SpecError, family_part
 
 
 def seed_key(seed: int):
@@ -29,56 +28,43 @@ def seed_key(seed: int):
                               seed >> 32)
 
 
-ACT = {"gelu_tanh": "gelu"}   # the program's names for the activations
-
-
 def program_config(conf: dict, dtype: str = "float32"):
-    """The program's ModelConfig with the widths of the benchmark's
-    configuration file; refuses a file the program cannot run as
-    written.  ``dtype`` other than float32 is the program's own
-    lower-precision path (the control), never a benchmark run."""
-    from repro.configs import get_config
-    m = conf["model"]
-    cfg = get_config(conf["program_config"])
-    cfg = replace(
-        cfg, dtype=dtype, n_layers=m["n_layers"], d_model=m["d_model"],
-        n_heads=m["n_heads"], n_kv_heads=m["n_heads"], d_ff=m["d_ff"],
-        vocab_size=m["vocab_size"], tie_embeddings=m["tie_embeddings"],
-        norm_eps=m["norm_eps"], moe_period=m["moe_period"],
-        moe=replace(cfg.moe, d_model=m["d_model"], d_ff=m["expert_d_ff"],
-                    n_experts=m["n_experts"], top_k=m["top_k"],
-                    capacity_factor=m["capacity_factor"],
-                    aux_loss_weight=m["aux_loss_weight"],
-                    z_loss_weight=m["z_loss_weight"],
-                    act=ACT.get(m["expert_act"], m["expert_act"])))
-    fixed = {"arch_type": "moe", "norm_type": "layernorm",
-             "use_rope": False, "qkv_bias": True, "ffn_bias": True,
-             "glu": False, "ffn_act": ACT.get(m["dense_act"],
-                                              m["dense_act"]),
-             "dtype": dtype, "logit_scale": 1.0,
-             "parallel_block": False, "attn_window": None,
-             "attn_chunk": None}
-    for k, want in fixed.items():
-        if getattr(cfg, k) != want:
-            raise SpecError(f"{conf['name']}: the program's {k} is "
-                            f"{getattr(cfg, k)!r}, the reference's {want!r}")
-    if cfg.moe.glu or \
-            cfg.moe.n_shared_experts or cfg.moe.normalize_topk:
-        raise SpecError(f"{conf['name']}: the program's experts are not "
-                        f"the reference's ({cfg.moe})")
-    return cfg
+    """The program's ModelConfig for a configuration file, built by its
+    family's ``bench/programs/<family>.py``.  ``dtype`` other than
+    float32 is the program's own lower-precision path (the control),
+    never a benchmark run."""
+    return family_part("programs", conf).program_config(conf, dtype)
 
 
 def adamw_config(conf: dict):
-    from repro.optim import AdamWConfig
-    o = conf["optimizer"]
-    if o["decay_min_rank"] != 2:
-        raise SpecError("the program decays leaves of rank >= 2 only")
-    return AdamWConfig(
-        lr=o["lr"], beta1=o["beta1"], beta2=o["beta2"], eps=o["eps"],
-        weight_decay=o["weight_decay"], clip_norm=o["clip_norm"],
-        warmup_steps=o["warmup_steps"], total_steps=o["total_steps"],
-        min_lr_frac=o["min_lr_frac"])
+    """The program's AdamWConfig for a configuration file, built by its
+    family's ``bench/programs/<family>.py``."""
+    return family_part("programs", conf).adamw_config(conf)
+
+
+MP_STAGES = ("mp_split", "ag_mp", "rs_mp")   # plan stages at the MP boundary
+
+
+def gate_layout(schedule: str) -> tuple:
+    """(kind, over_mp): the token pool a training schedule's gate sees,
+    as the program's plan for it declares.  ``kind`` is the ``cap`` of
+    its ``gate`` stage: ``pool`` (the device's whole pool), ``mp_shard``
+    (this MP rank's 1/N_MP slice of it) or ``esp_pool`` (the pool
+    gathered over ESP).  ``over_mp``: the plan has no stage that moves
+    between the layout replicated over MP and the split one (``MP_STAGES``),
+    so its input is already distinct over MP (the sequence-parallel
+    contract) and the pools split over MP as well as over the batch
+    axes."""
+    from repro.core import plan as planlib
+    from repro.core.perfmodel import MoELayerShape
+    from repro.core.pipeline import UNCHUNKED_OF
+    name = UNCHUNKED_OF.get(schedule, schedule)
+    if planlib.PLANS[name].decode_only:
+        raise SpecError(f"schedule {schedule!r} is for decoding only")
+    shape = MoELayerShape(B=1, L=8, M=8, H=8, E=2, k=1, f=1.0)
+    plan = planlib.plan_for_shape(name, shape)
+    kind = next(s.p("cap", "pool") for s in plan.stages if s.kind == "gate")
+    return kind, not any(s.kind in MP_STAGES for s in plan.stages)
 
 
 class Feed:

@@ -1,10 +1,19 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` names the cell, the
 cell names its configuration and traffic mix, and each of those, each
 per-layer metric and each kernel's operation count is a file of its own
-under ``bench/``.  Adding one is adding a file; nothing here changes."""
+under ``bench/``.  Adding one is adding a file; nothing here changes.
+
+A configuration belongs to a model family (its key ``family``; without
+it, the family ``model``).  A family is three files
+named after it: ``bench/programs/<family>.py`` (the program's entry for
+the configuration: ``program_config(conf, dtype)``,
+``adamw_config(conf)``), ``bench/reference/<family>.py`` (the plain
+reference: ``train_steps``) and ``bench/flops/<family>.py`` (model
+FLOPs: ``train_flops_per_token(m, seq_len)``)."""
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -12,6 +21,8 @@ from dataclasses import dataclass
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
+DEFAULT_FAMILY = "model"     # the family of configuration files without one
+FAMILY_PARTS = ("programs", "reference", "flops")
 
 
 class SpecError(Exception):
@@ -92,6 +103,25 @@ def kernel_counter(kernel: str, bench_dir: str = BENCH):
     if not os.path.exists(path):
         return None
     return load_module(path, f"bench_kernel_{kernel}").ops_bytes
+
+
+def family(conf: dict) -> str:
+    return conf.get("family", DEFAULT_FAMILY)
+
+
+def family_part(part: str, conf: dict, bench_dir: str = BENCH):
+    """The module ``bench/<part>/<family>.py`` of a configuration's
+    family, ``part`` one of FAMILY_PARTS; loaded once per process."""
+    if part not in FAMILY_PARTS:
+        raise SpecError(f"no family part {part!r} (have {FAMILY_PARTS})")
+    return _family_module(os.path.join(bench_dir, part,
+                                       f"{family(conf)}.py"))
+
+
+@functools.lru_cache(maxsize=None)
+def _family_module(path: str):
+    rel = os.path.relpath(path, BENCH).replace(os.sep, "_")
+    return load_module(path, "bench_family_" + rel[:-3].replace(".", "_"))
 
 
 def peaks(kind: str, bench_dir: str = BENCH) -> dict:

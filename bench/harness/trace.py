@@ -8,9 +8,10 @@ trace without a chip:
    (instruction, opcode, start ns, duration ns), and the host's spans (TraceAnnotation names and,
    with the Python tracer, Python frames) as (name, start, duration).
 2. The functions below reduce those lists, given the compiled step's
-   HLO (instruction -> op_name, the ``jax.named_scope`` path), to busy
-   and idle time, time under the MoE plan scopes, per-kernel time and
-   the breakdown.
+   HLO (instruction -> op_name, the ``jax.named_scope`` path, and which
+   instructions are collectives), to busy and idle time, time under the
+   MoE plan scopes, per-kernel time, exposed collective time and the
+   breakdown.
 
 Container ops (``while``, ``conditional``, ``call``) span the ops of
 their bodies and are left out of every sum and union.
@@ -43,6 +44,59 @@ def hlo_index(hlo_text: str) -> dict:
         name = line.split(" = ", 1)[0].replace("ROOT ", "").lstrip("%")
         m = re.search(r'op_name="([^"]*)"', line)
         out[name] = m.group(1) if m else ""
+    return out
+
+
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
+               "collective-permute", "collective-broadcast")
+MATMULS = ("convolution", "dot")
+KERNEL = 'custom_call_target="tpu_custom_call"'    # a Pallas kernel
+_CALLS = re.compile(r"\bcalls=%([\w.\-]+)")
+
+
+def collective_ops(hlo_text: str) -> set:
+    """Names of the instructions of a compiled HLO that are collectives:
+    a collective op (``all-to-all``, ``all-reduce-start``, ...), or a
+    fusion or async op whose computation holds one and no matmul or
+    Pallas kernel.  (A fusion that carries a matmul beside its
+    collective overlaps the two by construction: its time counts as
+    compute.)"""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            m = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)", line)
+            cur = comps.setdefault(m.group(1) if m else line, [])
+            continue
+        line = line.strip()
+        if cur is not None and line.startswith(("%", "ROOT %")):
+            name = line.split(" = ", 1)[0].replace("ROOT ", "").lstrip("%")
+            op = opcode(line)
+            mm = op.startswith(MATMULS) or KERNEL in line
+            cur.append((name, op, mm, _CALLS.findall(line)))
+
+    def is_coll(op):
+        return op.startswith(COLLECTIVES)
+
+    def holds(comp, seen=()):      # (holds a collective, holds a matmul)
+        coll = mm = False
+        for _, op, m, calls in comps.get(comp, ()):
+            coll |= is_coll(op)
+            mm |= m
+            for c in calls:
+                if c not in seen:
+                    a, b = holds(c, seen + (comp,))
+                    coll, mm = coll | a, mm | b
+        return coll, mm
+
+    out = set()
+    for instrs in comps.values():
+        for name, op, _, calls in instrs:
+            if is_coll(op):
+                out.add(name)
+            elif calls and (op == "fusion" or op.startswith("async-")):
+                got = [holds(c) for c in calls]
+                if any(c for c, _ in got) and not any(m for _, m in got):
+                    out.add(name)
     return out
 
 
@@ -140,6 +194,28 @@ def kernel_calls(ops, kernel: str, lo, hi) -> tuple:
         if name.rsplit(".", 1)[0] == kernel and lo <= s < hi:
             n, t = n + 1, t + d
     return n, t
+
+
+def exposed_collective(ops, collectives: set, lo, hi) -> int:
+    """Device ns in [lo, hi) in which a collective runs and no other op
+    does.  A collective runs for its own op's time and, for an async
+    pair (``<kind>-start`` ... ``<kind>-done``), from the start's
+    beginning to the done's end; pairs of one kind are matched in order,
+    which gives the same union as any matching in which each done
+    follows its start."""
+    coll, other, open_ = [], [], {}
+    for name, _, s, d in sorted(leaf_ops(ops), key=lambda o: o[2]):
+        if name not in collectives:
+            other.append((s, s + d))
+            continue
+        coll.append((s, s + d))
+        base = name.rsplit(".", 1)[0]
+        if base.endswith("-start"):
+            open_.setdefault(base[:-6], []).append(s)
+        elif base.endswith("-done") and open_.get(base[:-5]):
+            coll.append((open_[base[:-5]].pop(0), s + d))
+    return length(subtract(clip(union(coll), lo, hi),
+                           clip(union(other), lo, hi)))
 
 
 def op_group(name: str, index: dict, kernels, schedules) -> str:

@@ -13,7 +13,7 @@ import jax
 
 from bench.harness import compare, program
 from bench.harness import trace as tracelib
-from bench.harness.spec import Cell, generator
+from bench.harness.spec import Cell, SpecError, family_part, generator
 
 CHECK_STEPS = 3     # steps the reference follows
 TIMING_STEPS = 2    # steps after them that size the window
@@ -36,22 +36,55 @@ class CompileCounter:
             self.n += 1
 
 
-def capacity(cell: Cell, mesh_shape: dict) -> int:
-    """Per-expert capacity of the one token pool the program gates on
-    one chip (the whole batch): GShard's k * f * S / E, rounded up to a
-    multiple of 8 rows."""
-    if any(n > 1 for n in mesh_shape.values()):
-        raise ValueError(f"the reference states the gate pool of one chip "
-                         f"only, not of the mesh {mesh_shape}")
-    m = cell.config["model"]
-    S = cell.traffic["global_batch"] * cell.traffic["seq_len"]
-    c = math.ceil(m["top_k"] * m["capacity_factor"] * S / m["n_experts"])
-    return max(8, -(-c // 8) * 8)
+def gate_pools(cell: Cell, moe, mesh_shape: dict, dims, schedules) -> tuple:
+    """(n_pools, cap): the token pools the program gates one step's
+    batch in, and the per-expert capacity of each pool.
+
+    A device's pool is its tokens at the MoE boundary: distinct over the
+    mesh's batch axes (``ParallelDims.batch_axes``: with ESP = MP over
+    ``model``, over ``data`` alone), and split over the MP axes too
+    where the schedule's plan takes its input sequence-parallel
+    (``program.gate_layout``).  Its capacity is GShard's k * f * S_pool
+    / E rounded up to a multiple of max(8, n_mp)
+    (``core/moe.shard_pool_capacity``), with k, f and E from the
+    program's MoE config ``moe``.  A schedule whose gate sees its MP
+    rank's slice of the pool (S1 pauses MP before the gate) gates N_MP
+    pools of a 1/N_MP capacity each.  Every pool is a block of
+    contiguous rows.  A step whose MoE layers gate in
+    different layouts, or in one this does not state, is refused."""
+    def size(axes):
+        return math.prod(mesh_shape[a] for a in axes)
+    n_mp = size(tuple(dims.mp))
+    layouts = set()
+    for s in schedules or ("",):
+        kind, over_mp = program.gate_layout(s) if s else ("pool", False)
+        shard = size(tuple(dims.batch_axes)
+                     + (tuple(dims.mp) if over_mp else ()))
+        if kind not in ("pool", "mp_shard"):
+            raise SpecError(f"{cell.name}: schedule {s!r} gates a pool of "
+                            f"kind {kind!r}, which the reference does not "
+                            f"state")
+        layouts.add((shard, n_mp if kind == "mp_shard" else 1))
+    if len(layouts) != 1:
+        raise SpecError(f"{cell.name}: the step's MoE layers gate in pools "
+                        f"of different layouts ({sorted(schedules)} on "
+                        f"{mesh_shape}); the reference states one")
+    shard, split = layouts.pop()
+    mix = cell.traffic
+    if mix["global_batch"] % (shard * split):
+        raise SpecError(f"{cell.name}: batch {mix['global_batch']} does not "
+                        f"split into {shard * split} gate pools of whole "
+                        f"rows")
+    S = mix["global_batch"] * mix["seq_len"] // shard
+    align = max(8, n_mp)
+    c = math.ceil(moe.top_k * moe.capacity_factor * S / moe.n_experts)
+    return shard * split, max(align, -(-c // align) * align) // split
 
 
 def summarize(t: dict, hlo: str, kernels: dict, schedules: set):
     """Window, busy time and breakdown of a traced window, in place."""
     t["index"] = tracelib.hlo_index(hlo)
+    t["collectives"] = tracelib.collective_ops(hlo)
     t["window"] = lo, hi = tracelib.window(t["host"], "bench.traced_window")
     busy = [tracelib.length(tracelib.busy(ops, lo, hi))
             for ops in t["devices"].values()]
@@ -70,9 +103,11 @@ class Program:
     def __init__(self, cell: Cell, seed: int, devices, compiled=None,
                  dtype: str = "float32"):
         from repro.launch.mesh import local_mesh
+        from repro.core import autosched
         from repro.models import build_model
         from repro.train import Trainer
 
+        self.cell = cell
         conf, mix = cell.config, cell.traffic
         cfg = self.cfg = program.program_config(conf, dtype)
         if dtype != "float32":
@@ -82,7 +117,7 @@ class Program:
             xla = replace(cfg.kernel, backend="ref")
             cfg = self.cfg = replace(cfg, kernel=xla, moe=replace(
                 cfg.moe, kernel=replace(cfg.moe.kernel, backend="ref")))
-        self.mesh, dims = local_mesh(cfg, devices)
+        self.mesh, self.dims = local_mesh(cfg, devices)
         self.model = build_model(cfg)
         if dtype != "float32":
             # the control: the float32 path's weights, rounded to the
@@ -92,15 +127,17 @@ class Program:
             self.model.init = lambda k: jax.tree.map(
                 lambda a, t: a.astype(t.dtype), init32(k), like)
         self.opt_cfg = program.adamw_config(conf)
-        self.tr = Trainer(self.model, self.mesh, dims, self.opt_cfg)
+        self.tr = Trainer(self.model, self.mesh, self.dims, self.opt_cfg)
         self.key = program.seed_key(seed)
         self.params, self.opt_state = self.tr.setup(self.key)
-        self.gen = generator(mix).make(mix, conf["model"]["vocab_size"],
-                                       seed)
+        self.gen = generator(mix).make(mix, cfg.vocab_size, seed)
         self.feed = program.Feed(self.gen)
         if compiled is None:
+            # autosched's decisions are the process's: keep only this
+            # step's, which ``schedules`` reads
+            autosched.clear_cache()
             batch0 = self.feed.sharded_batch(0, self.mesh,
-                                             tuple(dims.batch_axes))
+                                             tuple(self.dims.batch_axes))
             compiled = self.tr.compile(self.params, self.opt_state, batch0)
         self.tr._step = self.compiled = compiled
 
@@ -134,20 +171,26 @@ class Program:
             return {self.cfg.moe.schedule}
         return {d.schedule for d in autosched.cache_info().values()}
 
+    def pools(self) -> tuple:
+        """(n_pools, cap) the program gates each step's batch in."""
+        return gate_pools(self.cell, self.cfg.moe, dict(self.mesh.shape),
+                          self.dims, self.schedules())
+
     def free(self):
         self.params = self.opt_state = self.compiled = None
         self.tr._step = self.tr._step_fn = None
         gc.collect()
 
 
-def reference(cell: Cell, key, gen, mesh_shape, device, dtype="float32",
-              fault="") -> dict:
-    """The reference's CHECK_STEPS steps on the same seed and batches."""
-    from bench.reference.model import train_steps
+def reference(cell: Cell, key, gen, pools, device, fault="") -> dict:
+    """The reference's CHECK_STEPS steps on the same seed and batches,
+    gated in the program's ``pools``, by the configuration's family's
+    ``bench/reference/<family>.py``."""
+    ref = family_part("reference", cell.config)
     batches = [gen.batch(i) for i in range(CHECK_STEPS)]
-    return train_steps(key, cell.config["model"], cell.config["optimizer"],
-                       batches, cap=capacity(cell, mesh_shape), n_pools=1,
-                       dtype=dtype, fault=fault, device=device)
+    return ref.train_steps(key, cell.config["model"],
+                           cell.config["optimizer"], batches, pools=pools,
+                           fault=fault, device=device)
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
@@ -157,7 +200,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     kernels = program.kernels_in_hlo(hlo)
     prog_check = prog.check_steps()
     schedules = prog.schedules()
-    mesh_shape = dict(prog.mesh.shape)
+    pools = prog.pools()
 
     # size the window from steps of its own
     t0 = time.perf_counter()
@@ -166,9 +209,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     step_s = (time.perf_counter() - t0) / TIMING_STEPS
     n_steps = max(2, round(seconds / step_s))
     setup_s = time.perf_counter() - t_start
-    log(f"bench: autosched picked {sorted(schedules)}; kernels in the "
-        f"compiled step: {sorted(kernels)}; window of {n_steps} steps "
-        f"(set-up step {step_s:.4f} s)")
+    log(f"bench: autosched picked {sorted(schedules)}, gating {pools[0]} "
+        f"pools of capacity {pools[1]}; kernels in the compiled step: "
+        f"{sorted(kernels)}; window of {n_steps} steps (set-up step "
+        f"{step_s:.4f} s)")
 
     counter = CompileCounter()
     counter.on = True
@@ -192,7 +236,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
 
     prog.free()     # the program's state goes before the reference runs
     t0 = time.perf_counter()
-    ref = reference(cell, prog.key, prog.gen, mesh_shape, devices[0])
+    ref = reference(cell, prog.key, prog.gen, pools, devices[0])
     ref_s = time.perf_counter() - t0
     numbers = compare.gaps(prog_check, ref)
     correct, lines = compare.judge(numbers, cell.limits)
@@ -203,5 +247,6 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     return {"correct": correct, "compare": lines, "setup_s": setup_s,
             "tokens_per_s": tokens_per_s, "n_steps": n_steps,
             "window_s": window_s, "peak": peak, "kernels": kernels,
-            "schedules": sorted(schedules), "traced": traced,
+            "schedules": sorted(schedules), "pools": pools,
+            "traced": traced,
             "compiles_in_window": counter.n}

@@ -21,17 +21,17 @@ batches and returns, per step, the loss, and after step one the
 per-leaf norm of the clipped gradient the optimizer took, and after the
 last step the per-leaf norm of the parameters' change.  The batch is
 gated in ``n_pools`` pools of contiguous rows with per-expert capacity
-``cap``, as the program gates it; gradients are accumulated pool by
-pool so that the whole batch never has to be live at once.  One period
+``cap``, as the harness states the program gates it (each pool's
+routing decided over the whole pool); gradients are accumulated pool by
+pool so that the whole batch never has to be live at once, and the
+experts' hidden activations one expert at a time.  One period
 of the layer pattern is compiled and scanned over, and the step number
 is traced, so that the three steps run one compiled program.
 
-``dtype="bfloat16"`` is the lower-precision control: weights and
-activations in bfloat16 with matmuls at the default precision (the
-cross-entropy's log-softmax and the optimizer stay float32).  The
-``fault`` argument plants a fault in the reference put in the
-program's place: ``half_batch`` (the loss and gradient over the first
-half of the rows only).
+It runs in float32 at ``highest`` matmul precision.  The ``fault``
+argument plants a fault in the reference put in the program's place:
+``half_batch`` (the loss and gradient over the first half of the rows
+only).
 """
 
 from __future__ import annotations
@@ -136,14 +136,14 @@ def _layernorm(p, x, eps):
             + p["bias"].astype(x.dtype))
 
 
-def _positions(L, D, dtype):
+def _positions(L, D):
     pos = jnp.arange(L, dtype=jnp.float32)[:, None]
     div = jnp.exp(jnp.arange(0, D, 2, dtype=jnp.float32)
                   * (-math.log(10000.0) / D))
     pe = jnp.zeros((L, D), jnp.float32)
     pe = pe.at[:, 0::2].set(jnp.sin(pos * div))
     pe = pe.at[:, 1::2].set(jnp.cos(pos * div))
-    return pe.astype(dtype)
+    return pe
 
 
 def _attention(p, x, m, rows_per_block):
@@ -190,9 +190,7 @@ def _moe(p, x, m, cap):
     Returns (y, aux_loss + z_loss)."""
     S, D = x.shape
     E, k = m["n_experts"], m["top_k"]
-    logits = x.astype(jnp.float32) @ p["wg"].astype(jnp.float32)
-    if x.dtype != jnp.float32:          # control: the router in low precision
-        logits = (x @ p["wg"].astype(x.dtype)).astype(jnp.float32)
+    logits = x @ p["wg"]
     probs = jax.nn.softmax(logits, axis=-1)
     gate_w, idx = lax.top_k(probs, k)
     slot = _capacity_slots(idx, E)
@@ -202,8 +200,12 @@ def _moe(p, x, m, cap):
     buf = jnp.zeros((E * cap + 1, D), x.dtype)
     buf = buf.at[flat.reshape(-1)].set(
         jnp.repeat(x, k, axis=0), mode="drop")[:-1].reshape(E, cap, D)
-    h = _act(m["expert_act"], jnp.einsum("ecd,edf->ecf", buf, p["w1"]))
-    out = jnp.einsum("ecf,efd->ecd", h, p["w2"]).reshape(E * cap, D)
+
+    def expert(args):           # one expert's buffer, remade in backward
+        xb, w1, w2 = args
+        return _act(m["expert_act"], xb @ w1) @ w2
+    out = lax.map(jax.checkpoint(expert), (buf, p["w1"], p["w2"]))
+    out = out.reshape(E * cap, D)
     out = jnp.concatenate([out, jnp.zeros((1, D), out.dtype)])
     y = jnp.einsum("sk,skd->sd", w.astype(x.dtype), out[flat])
     me = jnp.mean(probs, axis=0)
@@ -231,8 +233,8 @@ def _layer(p, x, *, m, kind, cap, rows_per_block):
     return x + _ffn(p["ffn"], h, m), jnp.float32(0.0)
 
 
-def pool_loss(params, tokens, labels, m, *, cap, n_tokens, n_pools,
-              dtype=jnp.float32, logit_rows=2048):
+def pool_loss(p, tokens, labels, m, *, cap, n_tokens, n_pools,
+              logit_rows=2048):
     """This pool's share of the batch loss: its summed token
     cross-entropy over ``n_tokens`` (all tokens of the batch) plus its
     router losses over ``n_pools``, so that the shares add up to the
@@ -240,8 +242,7 @@ def pool_loss(params, tokens, labels, m, *, cap, n_tokens, n_pools,
     b, L = tokens.shape
     D = m["d_model"]
     rows_per_block = max(1, (4 << 20) // (L * L))   # 4 rows at L = 1024
-    p = jax.tree.map(lambda a: a.astype(dtype), params)
-    x = p["embed"]["table"][tokens] + _positions(L, D, dtype)
+    x = p["embed"]["table"][tokens] + _positions(L, D)
     # one period of the layer pattern is scanned over, so that the
     # program compiles one period and not every layer
     kinds, P = layer_kinds(m), m["moe_period"]
@@ -296,12 +297,13 @@ def _leaf_norms(tree):
         a.astype(jnp.float32)))), tree)
 
 
-@partial(jax.jit, static_argnames=("m", "o", "cap", "n_pools", "dtype",
-                                   "fault"))
+@partial(jax.jit, static_argnames=("m", "o", "cap", "n_pools", "fault"),
+         donate_argnums=(0, 1, 2))
 def _step(params, mu, nu, tokens, labels, step, *, m, o, cap, n_pools,
-          dtype, fault):
+          fault):
     """One AdamW step; ``step`` (1, 2, ...) is traced, so that every step
-    runs one compiled program."""
+    runs one compiled program.  The state is donated: its new values take
+    the old ones' memory."""
     m, o = dict(m), dict(o)
     B, L = tokens.shape
     rows = B // n_pools
@@ -315,13 +317,11 @@ def _step(params, mu, nu, tokens, labels, step, *, m, o, cap, n_pools,
     def body(acc, xs):
         t, y = xs
         loss, g = jax.value_and_grad(pool_loss)(
-            params, t, y, m, cap=cap, n_tokens=n_tokens, n_pools=n_pools,
-            dtype=dtype)
+            params, t, y, m, cap=cap, n_tokens=n_tokens, n_pools=n_pools)
         return (acc[0] + loss, jax.tree.map(jnp.add, acc[1], g)), None
 
     zeros = jax.tree.map(jnp.zeros_like, params)
     (loss, grads), _ = lax.scan(body, (jnp.float32(0.0), zeros), (tok, lab))
-    grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
                          for g in jax.tree.leaves(grads)))
     scale = jnp.minimum(1.0, o["clip_norm"] / jnp.maximum(gnorm, 1e-9))
@@ -359,23 +359,22 @@ def _frozen(d: dict):
                         if isinstance(v, (int, float, str, bool))))
 
 
-def train_steps(key, model_cfg: dict, opt_cfg: dict, batches, *, cap: int,
-                n_pools: int, dtype: str = "float32", fault: str = "",
-                device=None) -> dict:
-    """Run ``len(batches)`` AdamW steps from the seeded weights.
+def train_steps(key, model_cfg: dict, opt_cfg: dict, batches, *, pools,
+                fault: str = "", device=None) -> dict:
+    """Run ``len(batches)`` AdamW steps from the seeded weights, each
+    batch gated in ``pools = (n_pools, cap)``: ``n_pools`` pools of
+    contiguous rows, ``cap`` slots per expert in each.
 
     Returns ``{"loss": [...], "grad_norms": {leaf: norm}, "change_norms":
     {leaf: norm}}``: the loss of every step, the per-leaf norms of the
     clipped gradient of step one, and the per-leaf norms of the change of
-    the parameters over all steps.  Runs at ``highest`` matmul precision
-    for float32 and at the default precision for the bfloat16 control.
+    the parameters over all steps.
     """
-    dt = jnp.dtype(dtype)
-    prec = "highest" if dt == jnp.float32 else "default"
     m, o = _frozen(model_cfg), _frozen(opt_cfg)
-    with jax.default_matmul_precision(prec), jax.default_device(device):
-        p0 = _init_jit(m)(key)
-        params = p0
+    n_pools, cap = pools
+    with jax.default_matmul_precision("highest"), \
+            jax.default_device(device):
+        params = _init_jit(m)(key)   # made again for the change, not kept
         mu = jax.tree.map(jnp.zeros_like, params)
         nu = jax.tree.map(jnp.zeros_like, params)
         losses, grad_norms = [], None
@@ -383,11 +382,11 @@ def train_steps(key, model_cfg: dict, opt_cfg: dict, batches, *, cap: int,
             params, mu, nu, loss, gn = _step(
                 params, mu, nu, jnp.asarray(tok), jnp.asarray(lab),
                 jnp.float32(i + 1), m=m, o=o, cap=cap, n_pools=n_pools,
-                dtype=dt, fault=fault)
+                fault=fault)
             losses.append(float(loss))
             if i == 0:
                 grad_norms = flat_norms(gn)
-        change = flat_norms(_change_norms(params, p0))
+        change = flat_norms(_change_norms(params, _init_jit(m)(key)))
     return {"loss": losses, "grad_norms": grad_norms,
             "change_norms": change}
 

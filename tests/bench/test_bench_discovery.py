@@ -85,3 +85,80 @@ def test_traffic_is_the_seeds():
     b = gen.make(cell.traffic, 30522, seed=3_000_000_001).batch(5)
     c = gen.make(cell.traffic, 30522, seed=3_000_000_002).batch(5)
     assert (a[0] == b[0]).all() and not (a[0] == c[0]).all()
+
+
+TOY = {
+    "programs/toy.py": (
+        '"""A toy family\'s program mapping."""\n\n\n'
+        'def program_config(conf, dtype="float32"):\n'
+        '    return ["toy program", conf["name"], dtype]\n\n\n'
+        'def adamw_config(conf):\n'
+        '    return ["toy adamw", conf["optimizer"]["lr"]]\n'),
+    "reference/toy.py": (
+        '"""A toy family\'s reference."""\n\n\n'
+        'def train_steps(key, model, opt, batches, *, pools, fault, device):\n'
+        '    return {"loss": [len(batches)], "pools": list(pools),\n'
+        '            "width": model["d_model"], "fault": fault}\n'),
+    "flops/toy.py": (
+        '"""A toy family\'s model FLOPs."""\n\n\n'
+        'def train_flops_per_token(m, seq_len):\n'
+        '    return 1e9 * m["n_layers"]\n'),
+}
+PROBE = """\
+import json
+from bench.harness import program, spec, train
+cell = spec.load_cell("toy.train-s1024")
+
+
+class Gen:
+    def batch(self, i):
+        return i, i
+
+
+run = {"cell": cell, "tokens_per_s": 1000.0, "chips": 1,
+       "peaks": {"bf16_flops_per_s": 1e13}}
+print(json.dumps({
+    "bench": spec.BENCH,
+    "program": program.program_config(cell.config),
+    "adamw": program.adamw_config(cell.config),
+    "reference": train.reference(cell, None, Gen(), (2, 8), None),
+    "mfu": spec.metric_reader("train.mfu")(run)}))
+"""
+
+
+def test_new_family_is_found(bench_copy):
+    """A family (program mapping, reference, model FLOPs) dropped into a
+    copy of ``bench/`` beside a configuration that names it: the
+    harness's dispatchers find each piece by the family's name, with no
+    file under ``bench/harness/`` changed."""
+    import subprocess
+    import sys
+    tmp, b = bench_copy
+    harness = {p: p.read_bytes() for p in (tmp / "bench" / "harness").rglob(
+        "*.py")}
+    for rel, text in TOY.items():
+        (tmp / "bench" / rel).write_text(text)
+    with open(tmp / "bench" / "configs" / "gpt2-moe.json") as f:
+        conf = json.load(f)
+    conf.update(name="toy", family="toy")
+    conf["model"]["n_layers"] = 3
+    (tmp / "bench" / "configs" / "toy.json").write_text(json.dumps(conf))
+    b["workloads"].append({"name": "toy.train-s1024", "config": "toy",
+                           "traffic": "train-s1024", "chips": 1,
+                           "why": "a family added as files"})
+    (tmp / "BENCHMARK.json").write_text(json.dumps(b))
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [str(tmp), os.path.join(ROOT, "src")]))
+    r = subprocess.run([sys.executable, "-c", PROBE], cwd=tmp, env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    got = json.loads(r.stdout.strip().splitlines()[-1])
+    assert got["bench"] == str(tmp / "bench")
+    assert got["program"] == ["toy program", "toy", "float32"]
+    assert got["adamw"] == ["toy adamw", conf["optimizer"]["lr"]]
+    assert got["reference"] == {"loss": [3], "pools": [2, 8],
+                                "width": 768, "fault": ""}
+    # 1000 tokens/s x 3e9 FLOPs / 1e13 FLOP/s = 30%
+    assert got["mfu"] == pytest.approx(30.0)
+    for p, data in harness.items():
+        assert p.read_bytes() == data

@@ -10,6 +10,7 @@ import jax
 import pytest
 
 from bench_helpers import tiny_cell
+from bench import faults
 from bench.harness import report, spec, train
 
 
@@ -24,13 +25,14 @@ def _run(cell):
     return res, line
 
 
-def _plant(monkeypatch, wrap):
-    from repro.train import loop
-    real = loop.make_train_step
+def _plant(monkeypatch, fault):
+    """Build the run's program with ``fault`` (``bench/faults.py``)."""
+    real = train.Program
 
-    def broken(*a, **k):
-        return wrap(real(*a, **k))
-    monkeypatch.setattr(loop, "make_train_step", broken)
+    def planted(*a, **k):
+        with faults.planted(fault):
+            return real(*a, **k)
+    monkeypatch.setattr(train, "Program", planted)
 
 
 @pytest.mark.parametrize("name", ["gpt2-moe.train-s1024",
@@ -45,12 +47,7 @@ def test_sound_run_is_correct(name):
 @pytest.mark.parametrize("name", ["gpt2-moe.train-s1024",
                                   "bert-moe.train-s128"])
 def test_state_left_unchanged_is_caught(name, monkeypatch):
-    def wrap(step):
-        def unchanged(p, o, b):
-            _, _, metrics = step(p, o, b)
-            return p, o, metrics
-        return unchanged
-    _plant(monkeypatch, wrap)
+    _plant(monkeypatch, "unchanged")
     cell = tiny_cell(name.split(".")[0], limits=_limits(name))
     res, line = _run(cell)
     assert not line["correct"]
@@ -60,12 +57,7 @@ def test_state_left_unchanged_is_caught(name, monkeypatch):
 @pytest.mark.parametrize("name", ["gpt2-moe.train-s1024",
                                   "bert-moe.train-s128"])
 def test_half_batch_is_caught(name, monkeypatch):
-    def wrap(step):
-        def half(p, o, b):
-            return step(p, o, {k: v[: v.shape[0] // 2] for k, v in
-                               b.items()})
-        return half
-    _plant(monkeypatch, wrap)
+    _plant(monkeypatch, "half_batch")
     cell = tiny_cell(name.split(".")[0], limits=_limits(name))
     res, line = _run(cell)
     assert not line["correct"], res["compare"]

@@ -2,6 +2,8 @@
 
 import bench_helpers  # noqa: F401  (the repo root and src on the path)
 
+import pytest
+
 from bench.flops import model as model_flops
 from bench.flops.kernels import expert_ffn_grouped
 from bench.flops.kernels import flash_attention
@@ -56,3 +58,43 @@ def test_expert_ffn_grouped_by_hand():
     assert nbytes == 4 * (tiles * M * F * 2 + rows * M) + 4 * 2 * rows * M
     assert expert_ffn_grouped.ops_bytes(call, {"loads": [None], "model": m}
                                         ) is None
+
+
+def test_model_flops_gqa_glu_by_hand():
+    """GQA (4 query heads, 2 key/value heads), a head width that is not
+    d / H, and GLU dense and expert FFNs."""
+    m = {"n_layers": 2, "d_model": 8, "n_heads": 4, "n_kv_heads": 2,
+         "head_dim": 4, "d_ff": 16, "dense_glu": True, "vocab_size": 10,
+         "moe_period": 2, "n_experts": 4, "top_k": 2, "expert_d_ff": 12,
+         "expert_glu": True}
+    L = 3
+    # q and o are 8 -> 16 and 16 -> 8, k and v 8 -> 8; scores and values
+    # over (L+1)/2 = 2 keys at q width 16
+    attn = 2 * (2 * 8 * 16 + 2 * 8 * 8) + 2 * 2 * 16 * 2
+    dense = 2 * 3 * 8 * 16
+    moe = 2 * 8 * 4 + 2 * 2 * 3 * 8 * 12
+    logits = 2 * 8 * 10
+    fwd = 2 * attn + dense + moe + logits
+    assert fwd == 3936
+    assert model_flops.train_flops_per_token(m, L) == 3 * fwd
+
+
+@pytest.mark.parametrize("name, L, V, want", [
+    ("gpt2-moe", 1024, 50257, 967_961_088),
+    ("bert-moe", 128, 30522, 827_476_992)])
+def test_todays_configs_exact_counts(name, L, V, want):
+    """The benchmark's two configurations (12 layers, d 768, 12 heads,
+    FFN 3072, every other FFN 8 experts top-2 of 3072): the counts the
+    per-layer metric ``train.mfu`` has read since it came."""
+    import json, os
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "..", "bench", "configs",
+                           f"{name}.json")) as f:
+        m = json.load(f)["model"]
+    d = 768
+    attn = 2 * 4 * d * d + 2 * 2 * d * (L + 1) / 2
+    dense = 2 * 2 * d * 3072
+    moe = 2 * d * 8 + 2 * 2 * 2 * d * 3072
+    fwd = 12 * attn + 6 * dense + 6 * moe + 2 * d * V
+    assert 3 * fwd == want
+    assert model_flops.train_flops_per_token(m, L) == want
