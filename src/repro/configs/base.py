@@ -59,6 +59,7 @@ class ModelConfig:
     norm_type: str = "rmsnorm"
     norm_eps: float = 1e-5
     qkv_bias: bool = False
+    qk_norm: bool = False               # RMSNorm over each head's q, k (Qwen3)
     ffn_bias: bool = False
     ffn_act: str = "silu"
     glu: bool = True
@@ -158,7 +159,9 @@ class ModelConfig:
         if moe is not None:
             e = min(moe.n_experts, n_experts or 4)
             moe = replace(moe, d_model=d, d_ff=max(32, moe.d_ff // 16),
-                          n_experts=e, top_k=min(moe.top_k, e))
+                          n_experts=e, top_k=min(moe.top_k, e),
+                          n_held=None if moe.n_held is None
+                          else min(moe.n_held, e))
         return replace(
             self, name=self.name + "-smoke", n_layers=n_layers, d_model=d,
             n_heads=heads, n_kv_heads=kv, head_dim=hd,

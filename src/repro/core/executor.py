@@ -132,7 +132,7 @@ class _Ctx:
 def _emit(st, vals, ctx):
     """Lower one stage; ``vals`` are its deps' values in order."""
     info = ctx.info
-    E = info.gate.n_experts
+    E, Eh = info.gate.n_experts, info.held
     Ne, Ns, Nm = info.n_ep, info.n_esp, info.n_mp
     comm = ctx.comm if st.wire else None
     kind = st.kind
@@ -157,8 +157,8 @@ def _emit(st, vals, ctx):
             return dispatch(tokens, g.expert_idx, g.slot_idx, cap,
                             ctx.placed.n_phys, info.kernel,
                             flat=_placed_flat(ctx, g, cap))
-        return dispatch(tokens, g.expert_idx, g.slot_idx, cap, E,
-                        info.kernel, flat=g.flat(cap, E))
+        return dispatch(tokens, g.expert_idx, g.slot_idx, cap, Eh,
+                        info.kernel, flat=g.flat(cap, E, info.n_held))
 
     if kind in ("mp_split", "rs_mp"):
         axes, n = _group(info, st.axes[0])
@@ -253,7 +253,7 @@ def _emit(st, vals, ctx):
     if kind == "combine":
         buf, (g, cap) = vals
         flat = _placed_flat(ctx, g, cap) if ctx.placed is not None \
-            else g.flat(cap, E)
+            else g.flat(cap, E, info.n_held)
         return combine(buf, g.expert_idx, g.slot_idx, g.weights, cap,
                        info.kernel, flat=flat)
 
@@ -295,32 +295,34 @@ def _emit_grouped(st, vals, ctx):
     unfused ops around explicit :func:`collectives.wire_roundtrip`.
     """
     info = ctx.info
-    E = info.gate.n_experts
+    E, Eh = info.gate.n_experts, info.held
     Ne, Ns = info.n_ep, info.n_esp
     comm = ctx.comm if st.wire else None
 
     if st.p("local"):
         tokens, (g, cap) = vals
+        flat = g.flat(cap, E, info.n_held)
         wd = getattr(comm, "wire_dtype", "f32") if comm is not None \
             else "f32"
         if coll.wire_raw_ok(comm):
             op = get_op("expert_ffn_grouped", cfg=info.kernel,
                         act=info.act, cap=cap, wire=wd)
-            return op(tokens, g.flat(cap, E), g.weights, ctx.w1,
+            return op(tokens, flat, g.weights, ctx.w1,
                       ctx.w3 if info.glu else None, ctx.w2)
         # fp8 wire: compose the unfused ops around the codec round-trip
         # (bit-identical to the pool path's encode/decode at both
         # boundaries; the FFN itself stays ragged/dropless)
-        d = dispatch(tokens, g.expert_idx, g.slot_idx, cap, E,
-                     info.kernel, flat=g.flat(cap, E))   # (E, cap, M)
+        d = dispatch(tokens, g.expert_idx, g.slot_idx, cap, Eh,
+                     info.kernel, flat=flat)             # (Eh, cap, M)
         d = coll.wire_roundtrip(d, comm)
-        cnt = jnp.minimum(g.aux["load"], cap).astype(jnp.int32)[:, None]
+        load = g.aux["load"] if Eh == E else g.aux["load"][:Eh]
+        cnt = jnp.minimum(load, cap).astype(jnp.int32)[:, None]
         op = get_op("expert_ffn_ragged", cfg=info.kernel, act=info.act)
-        h = op(d.reshape(E, 1, cap, -1), cnt, ctx.w1,
+        h = op(d.reshape(Eh, 1, cap, -1), cnt, ctx.w1,
                ctx.w3 if info.glu else None, ctx.w2)
-        h = coll.wire_roundtrip(h.reshape(E, cap, -1), comm)
+        h = coll.wire_roundtrip(h.reshape(Eh, cap, -1), comm)
         return combine(h, g.expert_idx, g.slot_idx, g.weights, cap,
-                       info.kernel, flat=g.flat(cap, E))
+                       info.kernel, flat=flat)
 
     h = vals[0]                                  # (El, G*c, M), maybe raw
     g, cap = ctx.gate
@@ -344,9 +346,10 @@ def _emit_grouped(st, vals, ctx):
         cnt = jnp.clip(cnt_p - ci * c, 0, c)
         nl = t.n_phys // Ne                      # local phys slots/rank
     else:
-        routed = jnp.minimum(g.aux["load"], float(cap)).astype(jnp.int32)
-        cnt = jnp.clip(routed - ci * c, 0, c)                    # (E,)
-        nl = E // Ne
+        load = g.aux["load"] if Eh == E else g.aux["load"][:Eh]
+        routed = jnp.minimum(load, float(cap)).astype(jnp.int32)
+        cnt = jnp.clip(routed - ci * c, 0, c)                    # (Eh,)
+        nl = Eh // Ne
     # Receive-side ragged metadata: sender g' = (i', j') delivered its
     # rows for OUR local expert el, so the valid-row count of block
     # rb[el, g'] is g''s routed count for global expert i*El + el —

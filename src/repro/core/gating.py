@@ -75,12 +75,12 @@ class GateResult:
     def __len__(self):
         return 4
 
-    def flat(self, cap: int, n_experts: int):
+    def flat(self, cap: int, n_experts: int, n_held: int = None):
         """Cached :func:`flat_slots` for this routing decision."""
-        key = (cap, n_experts)
+        key = (cap, n_experts, n_held)
         if key not in self._flat:
             self._flat[key] = flat_slots(self.expert_idx, self.slot_idx,
-                                         cap, n_experts)
+                                         cap, n_experts, n_held)
         return self._flat[key]
 
 
@@ -159,11 +159,18 @@ def topk_gate(x, wg, cfg: GateConfig, cap) -> "GateResult":
     return GateResult(expert_idx, slot_idx, weights, aux)
 
 
-def flat_slots(expert_idx, slot_idx, cap: int, n_experts: int):
-    """Flat capacity-buffer index per (token, choice); ``n_experts * cap``
-    marks a dropped choice (the registry ops' drop sentinel)."""
-    return jnp.where(slot_idx < cap, expert_idx * cap + slot_idx,
-                     n_experts * cap).astype(jnp.int32)
+def flat_slots(expert_idx, slot_idx, cap: int, n_experts: int,
+               n_held: int = None):
+    """Flat capacity-buffer index per (token, choice) into the buffer of
+    the held experts ``[0, n_held)`` (default all ``n_experts``);
+    ``n_held * cap`` marks a dropped choice (the registry ops' drop
+    sentinel), and a route to an expert not held here is one."""
+    kept = slot_idx < cap
+    n = n_experts if n_held is None else n_held
+    if n < n_experts:
+        kept &= expert_idx < n
+    return jnp.where(kept, expert_idx * cap + slot_idx,
+                     n * cap).astype(jnp.int32)
 
 
 def dispatch(x, expert_idx, slot_idx, cap: int, n_experts: int,

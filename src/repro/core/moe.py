@@ -40,7 +40,7 @@ from repro.parallel.mesh import ParallelDims, axis_size
 class MoEConfig:
     d_model: int
     d_ff: int                     # per-expert hidden size
-    n_experts: int
+    n_experts: int                # the router's width (E of the capacity)
     top_k: int = 1
     capacity_factor: float = 1.25
     n_shared_experts: int = 0     # llama4-style shared expert(s)
@@ -62,6 +62,13 @@ class MoEConfig:
     #   "auto" (read the live placement from the autosched registry at
     #   trace time — the rebalance loop's swap point) | a concrete
     #   ExpertPlacement (forced, e.g. the parity tests)
+    n_held: Optional[int] = None  # experts held here, [0, n_held): one
+    #   chip's share of a layer whose other experts live on chips that are
+    #   not there (routes to them take no slot and add nothing); None = all
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.n_held is None else self.n_held
 
     def gate_config(self) -> GateConfig:
         return GateConfig(
@@ -73,12 +80,15 @@ class MoEConfig:
 
 
 def init_moe_params(key, cfg: MoEConfig, dtype=jnp.float32) -> dict:
-    M, F, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """The router over all ``n_experts``; the weights of the ``held``
+    experts only."""
+    M, F, E = cfg.d_model, cfg.d_ff, cfg.held
     ks = jax.random.split(key, 6)
     scale_in = 1.0 / math.sqrt(M)
     scale_out = 1.0 / math.sqrt(F)
     p = {
-        "wg": jax.random.normal(ks[0], (M, E), jnp.float32) * scale_in,
+        "wg": jax.random.normal(ks[0], (M, cfg.n_experts),
+                                jnp.float32) * scale_in,
         "w1": jax.random.normal(ks[1], (E, M, F), dtype) * scale_in,
         "w2": jax.random.normal(ks[2], (E, F, M), dtype) * scale_out,
     }
@@ -101,7 +111,7 @@ def moe_param_specs(cfg: MoEConfig, mesh, dims: ParallelDims) -> dict:
     def esp_ok(n):
         return dims.esp and n % axis_size(mesh, dims.esp) == 0
 
-    E, F, M = cfg.n_experts, cfg.d_ff, cfg.d_model
+    E, F, M = cfg.held, cfg.d_ff, cfg.d_model
     e_ax = tuple(dims.ep) if ep_ok(E) else None
     f_ax = tuple(dims.esp) if esp_ok(F) else None
     specs = {
@@ -214,6 +224,12 @@ def apply_moe(x, params: dict, *, mesh, dims: ParallelDims, cfg: MoEConfig,
         raise ValueError(f"E={cfg.n_experts} not divisible by EP={n_ep}")
     if n_esp > 1 and cfg.d_ff % n_esp:
         raise ValueError(f"d_ff={cfg.d_ff} not divisible by ESP={n_esp}")
+    if not 0 < cfg.held <= cfg.n_experts:
+        raise ValueError(f"n_held={cfg.n_held} not in [1, {cfg.n_experts}]")
+    if cfg.held < cfg.n_experts and (n_ep > 1 or n_esp > 1):
+        raise ValueError(f"a share of {cfg.held} of {cfg.n_experts} experts "
+                         f"runs without the exchange: EP={n_ep}, "
+                         f"ESP={n_esp}")
 
     tokens_global = B * L
     batch_ax = dims.batch_axes
@@ -227,6 +243,8 @@ def apply_moe(x, params: dict, *, mesh, dims: ParallelDims, cfg: MoEConfig,
 
     s_local, cap = shard_pool_capacity(tokens_global, n_token_shard,
                                        n_mp, gate_cfg, infer=infer)
+    obs.emit("moe.share", n_experts=cfg.n_experts, n_held=cfg.held,
+             cap=cap)
     divisible = (tokens_global % max(n_token_shard, 1) == 0
                  and (seqpar or s_local % max(n_mp, 1) == 0)
                  and s_local > 0)
@@ -307,7 +325,7 @@ def apply_moe(x, params: dict, *, mesh, dims: ParallelDims, cfg: MoEConfig,
         saa_chunks=cfg.saa_chunks, pipeline_chunks=n_chunks,
         kernel=cfg.kernel,
         comm=CommConfig(wire_dtype=wire, scaling=comm.scaling),
-        placement=pl)
+        placement=pl, n_held=cfg.n_held)
 
     if sched == "dense_decode":
         body = _replicated_body

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
+from typing import Optional
 
 from repro.core.collectives import CommConfig
 from repro.core.executor import _aux_mean, execute, expert_ffn  # noqa: F401
@@ -70,6 +71,11 @@ class MoEShardInfo:
     kernel: KernelConfig = KernelConfig()  # hot-path op backend + tiles
     comm: CommConfig = CommConfig()  # wire dtype for the collectives
     placement: object = None  # ExpertPlacement (build_plan applies it)
+    n_held: Optional[int] = None  # experts held here (MoEConfig.n_held)
+
+    @property
+    def held(self) -> int:
+        return self.gate.n_experts if self.n_held is None else self.n_held
 
     @property
     def combined_group(self):

@@ -75,6 +75,31 @@ def _tiles(cap, F, block_t, block_f):
     return block_t, block_f, c_pad
 
 
+FUSED_VMEM_BUDGET = 14 * 2 ** 20   # of the 16 MiB of scoped VMEM a
+                                   # Mosaic kernel gets by default
+
+
+def fused_step_vmem_bytes(block_t, block_f, M, mats, itemsize):
+    """VMEM one step of ``expert_ffn_grouped`` holds: its ``mats`` weight
+    blocks, double-buffered, the gathered rows, the f32 accumulator and
+    output rows, and the f32 hidden slice(s) and FFN output of the
+    tile."""
+    weights = 2 * itemsize * mats * M * block_f
+    scratch = block_t * M * (itemsize + 4 + 4)
+    temps = 4 * block_t * (block_f * (mats - 1) + M)
+    return weights + scratch + temps
+
+
+def fused_block_f(block_t, block_f, M, mats, itemsize):
+    """``block_f`` halved, down to 128 lanes, until one step fits
+    ``FUSED_VMEM_BUDGET`` (a wide SwiGLU layer, such as Qwen3's M=2048
+    with three weight blocks, does not at 256)."""
+    while block_f > 128 and fused_step_vmem_bytes(
+            block_t, block_f, M, mats, itemsize) > FUSED_VMEM_BUDGET:
+        block_f //= 2
+    return block_f
+
+
 def _ragged_kernel(cnt_ref, x_ref, w1_ref, *refs, act, glu, block_t, G):
     if glu:
         w3_ref, w2_ref, o_ref = refs
@@ -239,6 +264,8 @@ def expert_ffn_grouped(x, flat_idx, weights, w1, w3, w2, *, cap,
     E, _, F = w1.shape
     glu = w3 is not None
     block_t, block_f, c_pad = _tiles(cap, F, block_t, block_f)
+    block_f = fused_block_f(block_t, block_f, M, 3 if glu else 2,
+                            w1.dtype.itemsize)
     n_t, n_f = c_pad // block_t, F // block_f
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

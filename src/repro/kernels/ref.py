@@ -7,14 +7,19 @@ import jax
 import jax.numpy as jnp
 
 
-def flash_attention_ref(q, k, v, *, causal=True, window=None, scale=None):
-    """q,k,v: (B, L, H, hd) (kv already head-repeated). Returns (B, L, H, hd)."""
+def flash_attention_ref(q, k, v, *, causal=True, window=None, scale=None,
+                        q_offset=None):
+    """q,k,v: (B, L, H, hd) (kv already head-repeated). Returns (B, L, H, hd).
+    ``q_offset``: the position of q's first row (default 0), for a block
+    of query rows against all the keys."""
     B, Lq, H, hd = q.shape
     Lk = k.shape[1]
     scale = scale if scale is not None else hd ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     qp = jnp.arange(Lq)[:, None]
+    if q_offset is not None:
+        qp = qp + q_offset
     kp = jnp.arange(Lk)[None, :]
     ok = jnp.ones((Lq, Lk), bool)
     if causal:

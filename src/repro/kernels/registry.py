@@ -428,15 +428,71 @@ def _flash_ref_fn(static):
     window = static.get("window")
     scale = static.get("scale")
 
-    def f(q, k, v):
+    def f(q, k, v, q_offset=None):
         H, K = q.shape[2], k.shape[2]
         if H != K:  # the oracle wants KV pre-repeated; the kernel is GQA-aware
             k = jnp.repeat(k, H // K, axis=2)
             v = jnp.repeat(v, H // K, axis=2)
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
-                                       scale=scale)
+                                       scale=scale, q_offset=q_offset)
 
     return f
+
+
+VJP_SCORE_BYTES = 1 << 30   # largest f32 score block of the attention VJP
+
+
+def vjp_query_blocks(B: int, H: int, Lq: int, Lk: int) -> int:
+    """Query blocks the attention VJP walks: the fewest, each a
+    power-of-two divisor of Lq, whose f32 scores ``B * H * bq * Lk * 4``
+    fit ``VJP_SCORE_BYTES`` (or the smallest such blocks where none
+    fits)."""
+    n = 1
+    while B * H * (Lq // n) * Lk * 4 > VJP_SCORE_BYTES and Lq % (2 * n) == 0:
+        n *= 2
+    return n
+
+
+def _flash_blocked_vjp(fwd_fn: Callable, ref_fn: Callable) -> Callable:
+    """``_with_ref_vjp`` for attention, in query blocks: one block
+    (``vjp_query_blocks``) is the oracle's VJP over the whole score
+    matrix; with more, a scan recomputes each block's rows against the
+    keys and accumulates dK and dV, so that one block's scores are live
+    at a time.  Softmax rows are independent, so it is the same
+    mathematics."""
+
+    @jax.custom_vjp
+    def op(*args):
+        return fwd_fn(*args)
+
+    def fwd(*args):
+        return fwd_fn(*args), args
+
+    def bwd(args, g):
+        q, k, v = args
+        B, Lq, H, hd = q.shape
+        n = vjp_query_blocks(B, H, Lq, k.shape[1])
+        if n == 1:
+            return jax.vjp(ref_fn, *args)[1](g)
+        bq = Lq // n
+
+        def blocks(a):          # (B, Lq, H, hd) -> (n, B, bq, H, hd)
+            return jnp.moveaxis(a.reshape(B, n, bq, H, hd), 1, 0)
+
+        def step(carry, xs):
+            i, q_i, g_i = xs
+            _, vjp = jax.vjp(lambda a, b, c: ref_fn(a, b, c, i * bq),
+                             q_i, k, v)
+            dq_i, dk_i, dv_i = vjp(g_i)
+            return (carry[0] + dk_i, carry[1] + dv_i), dq_i
+
+        (dk, dv), dq = jax.lax.scan(
+            step, (jnp.zeros_like(k), jnp.zeros_like(v)),
+            (jnp.arange(n), blocks(q), blocks(g)))
+        return jnp.moveaxis(dq, 0, 1).reshape(q.shape), dk, dv
+
+    op.defvjp(fwd, bwd)
+    return op
 
 
 @register("flash_attention", "ref")
@@ -450,4 +506,4 @@ def _flash_pallas(cfg, static):
         _flash_mod.flash_attention, causal=static.get("causal", True),
         window=static.get("window"), scale=static.get("scale"),
         block_q=cfg.block_q, block_k=cfg.block_k, interpret=cfg.interpret)
-    return jax.jit(_with_ref_vjp(fwd, _flash_ref_fn(static)))
+    return jax.jit(_flash_blocked_vjp(fwd, _flash_ref_fn(static)))

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.ref import rmsnorm_ref
 from repro.models.layers import apply_rope, dense_init
 
 
@@ -36,6 +37,8 @@ class AttnConfig:
     window: int | None = None     # sliding window (tokens), None = full
     chunk: int | None = None      # llama4-style chunked local attention
     qkv_bias: bool = False
+    qk_norm: bool = False         # RMSNorm over each head's q and k (Qwen3)
+    norm_eps: float = 1e-6        # the QK-norm's epsilon
     softmax_scale: float | None = None
     flash_block: int = 512        # KV block for the scan path
     flash_threshold: int = 2048   # use scan path above this seq length
@@ -60,6 +63,9 @@ def init_attn(key, cfg: AttnConfig, dtype=jnp.float32):
         p["bq"] = jnp.zeros((H * hd,), dtype)
         p["bk"] = jnp.zeros((K * hd,), dtype)
         p["bv"] = jnp.zeros((K * hd,), dtype)
+    if cfg.qk_norm:
+        p["q_norm"] = jnp.ones((hd,), jnp.float32)
+        p["k_norm"] = jnp.ones((hd,), jnp.float32)
     return p
 
 
@@ -76,7 +82,21 @@ def attn_specs(mesh, mp_axes, cfg: AttnConfig):
         p["bq"] = P(q_ax)
         p["bk"] = P(kv_sp)
         p["bv"] = P(kv_sp)
+    if cfg.qk_norm:
+        p["q_norm"] = P(None)
+        p["k_norm"] = P(None)
     return p
+
+
+def qk_norm(p, cfg: AttnConfig, q, k):
+    """RMSNorm over head_dim of each head's q and k, each with a learned
+    scale of head_dim, before RoPE (Qwen3); q and k as they are where
+    the config has none.  Every path that builds q and k calls it."""
+    if not cfg.qk_norm:
+        return q, k
+    with jax.named_scope("qk_norm"):
+        return (rmsnorm_ref(q, p["q_norm"], cfg.norm_eps),
+                rmsnorm_ref(k, p["k_norm"], cfg.norm_eps))
 
 
 def _mask_bias(cfg: AttnConfig, q_pos, k_pos):
@@ -247,6 +267,7 @@ def apply_attn(p, cfg: AttnConfig, x, *, positions=None, kv_x=None,
             q = q + p["bq"].reshape(H, hd)
             k = k + p["bk"].reshape(K, hd)
             v = v + p["bv"].reshape(K, hd)
+        q, k = qk_norm(p, cfg, q, k)
         # the Pallas kernel derives positions from block indices, so it is
         # only valid for the default contiguous-from-zero layout (record
         # before the arange defaults are filled in)
@@ -326,6 +347,7 @@ def prefill_attn(p, cfg: AttnConfig, x, cache, lengths, *, kernel=None,
         q = q + p["bq"].reshape(H, hd)
         k = k + p["bk"].reshape(K, hd)
         v = v + p["bv"].reshape(K, hd)
+    q, k = qk_norm(p, cfg, q, k)
     positions = jnp.arange(L)
     if cfg.use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
@@ -396,6 +418,7 @@ def paged_chunk_attn(p, cfg: AttnConfig, x, arena, table, starts, lens):
         q = q + p["bq"].reshape(H, hd)
         k = k + p["bk"].reshape(K, hd)
         v = v + p["bv"].reshape(K, hd)
+    q, k = qk_norm(p, cfg, q, k)
     offs = jnp.arange(C)
     qpos = starts[:, None] + offs[None, :]                # (B, C) absolute
     valid_q = offs[None, :] < lens[:, None]               # (B, C)
@@ -478,6 +501,7 @@ def decode_attn(p, cfg: AttnConfig, x, cache, step, *, kv_cache_static=None,
         q = q + p["bq"].reshape(H, hd)
         k = k + p["bk"].reshape(K, hd)
         v = v + p["bv"].reshape(K, hd)
+    q, k = qk_norm(p, cfg, q, k)
     vec = jnp.ndim(step) > 0                 # per-row positions (engine)
     if cfg.use_rope:
         pos = step[:, None] if vec else jnp.full((1,), step)

@@ -39,6 +39,7 @@ def attn_config(cfg: ModelConfig, kind: str, cross: bool = False) -> AttnConfig:
         window=None if (full or cross) else cfg.attn_window,
         chunk=None if (full or cross) else cfg.attn_chunk,
         qkv_bias=cfg.qkv_bias and not cross,
+        qk_norm=cfg.qk_norm and not cross, norm_eps=cfg.norm_eps,
         masked_cache_update=cfg.cache_masked_update,
         context_parallel=cfg.context_parallel_decode)
 

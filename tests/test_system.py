@@ -79,7 +79,17 @@ class TestServeLoop:
     def test_decode_matches_prefill_dense(self):
         """Greedy decode over a teacher-forced prompt must match the
         full-sequence forward logits (KV-cache correctness)."""
-        cfg = get_config("mistral-nemo-12b").reduced()
+        self._decode_matches_forward(get_config("mistral-nemo-12b").reduced())
+
+    def test_decode_matches_prefill_qk_norm(self):
+        """The same with RMSNorm over each head's q and k (Qwen3): the
+        decode path normalises the cached k as the forward does."""
+        from dataclasses import replace
+        cfg = replace(get_config("mistral-nemo-12b").reduced(), qk_norm=True)
+        self._decode_matches_forward(cfg)
+
+    @staticmethod
+    def _decode_matches_forward(cfg):
         mesh, dims = _mesh_dims(cfg)
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))

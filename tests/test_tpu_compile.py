@@ -100,6 +100,7 @@ def test_kernel_compiles_for_v5e(op, one_chip, no_compile_cache):
     (16, 1024, 12, 12, 64),    # gpt2-moe.train-s1024
     (128, 128, 12, 12, 64),    # bert-moe.train-s128
     (4, 1024, 32, 8, 128),     # GQA, rep 4
+    (4, 4096, 32, 4, 128),     # qwen3-moe.train-s4096: GQA 8:1
 ])
 def test_flash_attention_heads_per_step_compiles_for_v5e(
         B, L, H, K, hd, one_chip, no_compile_cache):
@@ -127,4 +128,26 @@ def test_attention_kernel_compiles_on_four_chip_mesh(topo, no_compile_cache):
                                  mesh, P("data", None, "model", None)))
     op = _flash_op(cfg, PALLAS, x, x, mesh, dims)
     compiled = jax.jit(op).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grouped_glu_kernel_compiles_at_qwen3_widths(one_chip,
+                                                     no_compile_cache):
+    """The fused grouped kernel's SwiGLU path at Qwen3-30B-A3B's widths
+    (M=2048, F=768) for one chip's 16 held experts, 16384 tokens top-8
+    at capacity 1280: three weight blocks of 256 would overflow the
+    kernel's scoped VMEM, so ``fused_block_f`` takes 128."""
+    from repro.kernels.expert_ffn_grouped import fused_block_f
+    f32, i32 = jnp.float32, jnp.int32
+
+    def s(shape, dt=f32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    assert fused_block_f(128, 256, 2048, 3, 4) == 128
+    assert fused_block_f(128, 256, M, 2, 4) == 256     # gpt2-moe's
+    op = get_op("expert_ffn_grouped", cfg=PALLAS, act="silu", cap=1280,
+                wire="f32")
+    compiled = jax.jit(op).lower(
+        s((16384, 2048)), s((16384, 8), i32), s((16384, 8)),
+        s((16, 2048, 768)), s((16, 2048, 768)), s((16, 768, 2048))
+    ).compile()
     assert "tpu_custom_call" in compiled.as_text()
